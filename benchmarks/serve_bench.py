@@ -79,6 +79,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
+from repro.launch.mesh import make_mesh
+
 
 def _bench_mesh():
     """The mesh every bench engine runs on: all visible devices on the
@@ -87,7 +89,7 @@ def _bench_mesh():
     regression gate compared across different effective meshes; building
     one mesh here and threading it through every engine (drafters
     included) makes the recorded ``mesh_shape`` the truth."""
-    return jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    return make_mesh((len(jax.devices()), 1))
 
 
 def _paged_attn_path() -> str:

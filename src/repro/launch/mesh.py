@@ -1,12 +1,22 @@
-"""Production mesh construction (TPU v5e target).
+"""Mesh construction — the one place a ``jax.sharding.Mesh`` is built.
 
-A FUNCTION, not a module-level constant — importing this module never
+FUNCTIONS, not module-level constants — importing this module never
 touches jax device state (required so smoke tests see 1 CPU device while
 the dry-run sees 512 placeholder devices).
+
+Every axis is ``AxisType.Auto``: the sharding rules in
+``distributed/sharding.py`` place arrays with ``NamedSharding`` and let
+the compiler propagate the rest.  ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which eager ops on sharded arrays (page-table
+updates) and contractions over two sharded dims raise
+``ShardingTypeError`` instead of compiling a collective.
 """
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12        # per chip
@@ -14,22 +24,35 @@ HBM_BW = 819e9                  # bytes/s per chip
 ICI_BW = 50e9                   # bytes/s per link
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()[:n]
+def make_mesh(shape, *, devices=None):
+    """Mesh of ``shape`` over ``devices`` (default: the first
+    ``prod(shape)`` of ``jax.devices()``), all axes ``Auto``: axes
+    ("data", "model"), or ("pod", "data", "model") for a 3-D shape."""
+    shape = tuple(int(s) for s in shape)
+    n = math.prod(shape)
+    if devices is None:
+        devices = jax.devices()[:n]
     if len(devices) < n:
         raise RuntimeError(
-            f"mesh {shape} needs {n} devices, found {len(jax.devices())} — "
-            "the dry-run launcher must set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            "any jax import")
-    return jax.make_mesh(shape, axes, devices=devices)
+            f"mesh {shape} needs {n} devices, found {len(devices)} — on "
+            "the CPU, set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{n} before any jax import")
+    return jax.make_mesh(shape, ("pod", "data", "model")[-len(shape):],
+                         devices=list(devices)[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    return make_mesh((2, 16, 16) if multi_pod else (16, 16))
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (same axis names, sizes 1x1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1))
+
+
+def make_local_mesh():
+    """("data", "model") = (all local devices, 1): the watershed / batch
+    axis spans every chip of this host."""
+    devices = jax.local_devices()
+    return make_mesh((len(devices), 1), devices=devices)

@@ -56,6 +56,7 @@ from repro import checkpoint as ckpt
 from repro.configs import get_config, smoke_variant
 from repro.core import domst
 from repro.data.pipeline import make_domst_windows, stacked_test_batch
+from repro.launch.compile_cache import init_compile_cache
 from repro.models import transformer as tfm
 from repro.obs import (
     MetricRegistry, Tracer, derive_request_metrics, percentiles, profiler,
@@ -153,21 +154,31 @@ def make_drafter(args, cfg, engine):
     return ModelDrafter(draft_cfg, **kw)
 
 
-def serve_lm(args) -> dict:
+def lm_config(args):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)
     if not cfg.supports_decode():
         raise SystemExit(f"{cfg.name} is encoder-only; no decode path")
-    params = tfm.init(cfg, jax.random.key(args.seed))
-    reqs = make_requests(cfg, args)
+    return cfg
+
+
+def make_engine(cfg, args, reqs) -> InferenceEngine:
+    """The serving engine the CLI flags describe, sized to ``reqs``."""
     max_len = args.max_len or max(
         len(r.prompt) + r.max_new + (cfg.num_patches or 0) for r in reqs)
-    engine = InferenceEngine(cfg, slots=args.batch_size, max_len=max_len,
-                             paged=args.page_size > 0,
-                             page_size=args.page_size or 16,
-                             num_pages=args.num_pages or None,
-                             prefill_chunk=args.prefill_chunk)
+    return InferenceEngine(cfg, slots=args.batch_size, max_len=max_len,
+                           paged=args.page_size > 0,
+                           page_size=args.page_size or 16,
+                           num_pages=args.num_pages or None,
+                           prefill_chunk=args.prefill_chunk)
+
+
+def serve_lm(args) -> dict:
+    cfg = lm_config(args)
+    params = tfm.init(cfg, jax.random.key(args.seed))
+    reqs = make_requests(cfg, args)
+    engine = make_engine(cfg, args, reqs)
     if args.ckpt:
         params = engine.restore_params(args.ckpt, params)
     state = engine.init_state(params)
@@ -214,6 +225,7 @@ def serve_lm(args) -> dict:
            "num_pages": engine.num_pages,
            "prefill_chunk": engine.prefill_chunk,
            "prefill_chunks": st["prefill_chunks"],
+           "decode_steps": st["decode_steps"],
            "spec_k": args.spec_k,
            "drafter": args.drafter if args.spec_k else None,
            "spec_steps": st["spec_steps"],
@@ -272,7 +284,8 @@ def serve_lm(args) -> dict:
         tracer.save(args.trace_out)
     if args.metrics_out:
         registry.dump_jsonl(args.metrics_out)
-    return out
+    # the printed record, plus every emitted stream for in-process callers
+    return {**out, "streams": generated}
 
 
 def serve_domst(args) -> dict:
@@ -307,7 +320,7 @@ def serve(args) -> dict:
     return serve_lm(args)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -409,7 +422,12 @@ def main() -> None:
     ap.add_argument("--days", type=int, default=400,
                     help="domst: synthetic record length (must match)")
     ap.add_argument("--seed", type=int, default=0)
-    serve(ap.parse_args())
+    return ap
+
+
+def main() -> None:
+    init_compile_cache()
+    serve(build_parser().parse_args())
 
 
 if __name__ == "__main__":

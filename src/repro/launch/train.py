@@ -29,8 +29,9 @@ and LM paths.  The same ``--ckpt`` file is the hand-off into serving:
 sharded inference engine (Dom-ST forecast or LM continuous batching)
 without ever instantiating the optimizer moments.
 
-On this CPU container the default mesh is 1x1; the same script drives the
-production mesh on real hardware (``--mesh pod|multipod``).
+The default mesh is 1x1 (one device); ``--mesh local`` spans every local
+device on the "data" axis (the watershed axis of stacked Dom-ST), and
+``--mesh pod|multipod`` builds the production meshes.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch domst --watersheds 4 --epochs 3
@@ -56,7 +57,10 @@ from repro.data.pipeline import (
     stacked_test_batch, train_split, train_test_split,
 )
 from repro.data.tokens import TokenSource, synthetic_token_batch
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.compile_cache import init_compile_cache
+from repro.launch.mesh import (
+    make_host_mesh, make_local_mesh, make_production_mesh,
+)
 from repro.metrics import Meter
 from repro.models import transformer as tfm
 from repro.obs import MetricRegistry, profiler
@@ -70,6 +74,8 @@ EVAL_SEED_OFFSET = 2**31
 def _make_mesh(name: str):
     if name == "host":
         return make_host_mesh()
+    if name == "local":
+        return make_local_mesh()
     return make_production_mesh(multi_pod=name == "multipod")
 
 
@@ -122,6 +128,7 @@ def train_domst(args) -> dict:
     mesh = _make_mesh(args.mesh)
     if args.profile_dir:                # device-trace window over the run
         profiler.start(args.profile_dir)
+    epoch_losses = []                   # stacked: mean loss at each epoch end
 
     if args.mode == "stacked":          # IP-D: all watersheds per step
         engine = Engine.for_domst(cfg, tc, mesh=mesh, stacked=True)
@@ -153,6 +160,7 @@ def train_domst(args) -> dict:
                       f"{float(jnp.mean(ev['nse'])):.4f}", flush=True)
             if step % spe == 0:         # epoch boundary
                 meter.update(loss=float(jnp.mean(m["loss"])))
+                epoch_losses.append(meter.last("loss"))
                 print(f"epoch {step // spe - 1} mean loss "
                       f"{meter.last('loss'):.4f} "
                       f"({meter.elapsed():.1f}s)", flush=True)
@@ -187,6 +195,7 @@ def train_domst(args) -> dict:
     result = {"arch": args.arch, "mode": args.mode,
               "accum_steps": args.accum_steps, "prefetch": args.prefetch,
               "mean_nse": float(np.mean(nses)), "nse": nses,
+              "epoch_losses": epoch_losses,
               "wall_s": meter.elapsed(), **_train_metrics(registry)}
     print(json.dumps(result, indent=2))
     if args.metrics_out:
@@ -194,7 +203,9 @@ def train_domst(args) -> dict:
     if args.ckpt:                       # stacked only (guarded above)
         engine.save(args.ckpt, state)   # the full multi-replica TrainState
         print("saved", args.ckpt)
-    return result
+    # the printed record, plus the final (last watershed's, if sequential)
+    # live TrainState for in-process callers
+    return {**result, "state": state}
 
 
 def train_lm(args) -> dict:
@@ -257,7 +268,7 @@ def train_lm(args) -> dict:
     return result
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -271,10 +282,11 @@ def main() -> None:
     ap.add_argument("--days", type=int, default=400)
     ap.add_argument("--mode", choices=("stacked", "sequential"),
                     default="stacked")
-    ap.add_argument("--mesh", choices=("host", "pod", "multipod"),
+    ap.add_argument("--mesh", choices=("host", "local", "pod", "multipod"),
                     default="host",
-                    help="host: 1x1 CPU mesh; pod/multipod: the production "
-                         "TPU meshes (need 256/512 devices)")
+                    help="host: 1x1 mesh on one device; local: (all local "
+                         "devices, 1) data x model; pod/multipod: the "
+                         "production TPU meshes (need 256/512 devices)")
     ap.add_argument("--accum-steps", type=int, default=1,
                     help="gradient-accumulation microbatches per step")
     ap.add_argument("--prefetch", type=int, default=2,
@@ -296,7 +308,12 @@ def main() -> None:
                     help="open a jax.profiler trace window over the "
                          "training loop, writing device traces here; "
                          "Engine.step is TraceAnnotation-scoped")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    init_compile_cache()
+    args = build_parser().parse_args()
     if args.arch.startswith("domst"):
         train_domst(args)
     else:

@@ -24,18 +24,6 @@ from repro.configs.base import ModelConfig, MoEConfig
 from repro.distributed.sharding import ParamFactory, constrain
 from repro.models.mlp import _act, mlp_block, mlp_params
 
-try:                                  # newer jax: top-level export
-    from jax import shard_map
-except ImportError:                   # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma
-# independently of where shard_map is exported, so probe the signature
-import inspect as _inspect
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False})
-
 
 def moe_params(mk: ParamFactory, cfg: ModelConfig):
     m = cfg.moe
@@ -191,11 +179,11 @@ def moe_block_sharded(params, cfg: ModelConfig, x: jax.Array, mesh):
             y = y + mlp_block(p["shared"], cfg.act, xl)
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(wspec, xspec),
         out_specs=(xspec, P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(dict(params), x)
     return y, aux
 

@@ -684,6 +684,17 @@ class InferenceEngine:
                              jnp.asarray(draft_len, jnp.int32),
                              jnp.asarray(active, bool))
 
+    def lower_decode(self, state: InferenceState) -> jax.stages.Lowered:
+        """Lower the fused all-slot decode step for ``state`` (arrays or
+        ``jax.ShapeDtypeStruct`` leaves) without running it — the program
+        :meth:`decode` runs, for callers that inspect what it compiles to
+        (e.g. that paged attention is the Pallas kernel)."""
+        if self.paged:
+            jfn = self._get_jit("decode_paged", state)
+            return self._run(jfn.lower, state,
+                             jax.ShapeDtypeStruct((self.slots,), bool))
+        return self._run(self._get_jit("decode", state).lower, state)
+
     def decode(self, state: InferenceState, active=None):
         """One decode step over ALL slots: each slot's last token advances
         its own position counter.  Returns (state, greedy tokens (slots,));

@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs import TrainConfig, get_config, smoke_variant
 from repro.core import domst
+from repro.launch.mesh import make_mesh
 from repro.optim import make_optimizer
 from repro.train import Engine, TrainState
 
@@ -124,7 +125,7 @@ def test_stacked_engine_shards_watersheds_on_mesh(rng):
     the engine's numerics match the single-device reference."""
     cfg = get_config("domst")
     tc = TrainConfig(learning_rate=1e-3, total_steps=10, warmup_steps=1)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     eng = Engine.for_domst(cfg, tc, mesh=mesh, stacked=True)
     state = eng.init_state(jax.random.key(1),
                            domst.init_stacked(cfg, jax.random.key(1), 4))

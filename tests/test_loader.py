@@ -20,6 +20,7 @@ from repro.data.pipeline import (
     train_test_split,
 )
 from repro.data.tokens import TokenSource, synthetic_token_batch
+from repro.launch.mesh import make_mesh
 from repro.train import Engine
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -208,7 +209,7 @@ def test_loader_outputs_sharded_on_mesh():
     ip = InputPipeline(windows, batch_size=8, seed=0)
     cfg = get_config("domst")
     tc = TrainConfig(learning_rate=1e-3, total_steps=10, warmup_steps=1)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     eng = Engine.for_domst(cfg, tc, mesh=mesh, stacked=True)
     state = eng.init_state(
         jax.random.key(0), domst.init_stacked(cfg, jax.random.key(0), 4))

@@ -21,6 +21,7 @@ from repro.data.pipeline import make_domst_windows, stacked_test_batch
 from repro.distributed.sharding import (
     cache_needs_seq_shard, make_rules, tree_shardings,
 )
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.layers import unembed
 from repro.serve import InferenceEngine, Request, Scheduler
@@ -605,7 +606,7 @@ def test_inference_state_shardings_match_rule_tables():
     ``cache_needs_seq_shard``: olmo's divisible kv_heads shard over
     "model" (cache_seq replicated), while a ffn-mode variant flips the
     cache's sequence axis onto "model" instead."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     for arch, ffn_mode in (("olmo-1b", False), ("olmo-1b", True)):
         cfg = smoke_variant(get_config(arch))
         if ffn_mode:
@@ -640,7 +641,7 @@ def test_mesh_serving_matches_single_device_tokens():
     cfg = smoke_variant(get_config("qwen2-1.5b"))
     lens = [8, 6, 7, 8]
     ref, _ = _serve(cfg, _requests(cfg, lens), slots=4)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     got, _ = _serve(cfg, _requests(cfg, lens), slots=4, mesh=mesh)
     assert got == ref
 
@@ -655,7 +656,7 @@ def test_paged_vs_contiguous_parity_on_mesh(arch):
     contiguous slot-major baseline, across attention-only, local/global
     and recurrent-hybrid architectures."""
     cfg = smoke_variant(get_config(arch))
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     lens = [8, 5, 7, 6]
     ref, _ = _serve(cfg, _requests(cfg, lens), slots=4, mesh=mesh)
     got, _ = _serve(cfg, _requests(cfg, lens), slots=4, mesh=mesh,
@@ -669,7 +670,7 @@ def test_paged_pool_shardings_match_rule_tables():
     pages over "data" and — per cache_needs_seq_shard — the model axis on
     kv_heads (heads mode) vs the within-page offset axis (ffn mode).  The
     page table rides the slot axis like the position counters."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     for ffn_mode in (False, True):
         cfg = smoke_variant(get_config("olmo-1b"))
         if ffn_mode:

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke_variant
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.serve import (
     InferenceEngine, NgramDrafter, Request, SamplingParams, Scheduler,
@@ -350,7 +351,7 @@ def test_sampled_mesh_matches_single_device():
     cfg = smoke_variant(get_config("qwen2-1.5b"))
     ref, _ = _serve(cfg, _requests(cfg, params=MIXED), slots=4, paged=True,
                     page_size=4)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     got, _ = _serve(cfg, _requests(cfg, params=MIXED), slots=4, mesh=mesh,
                     paged=True, page_size=4,
                     sched_kw={"spec_k": 3, "drafter": NgramDrafter()})

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke_variant
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.serve import (
     InferenceEngine, ModelDrafter, NgramDrafter, Request, Scheduler,
@@ -288,7 +289,7 @@ def test_spec_parity_on_mesh(arch):
     correct drafter, speculative streams bit-match the spec_k=0 mesh run
     across attention-only, local/global and recurrent-hybrid archs."""
     cfg = smoke_variant(get_config(arch))
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     ref, _ = _serve(cfg, _requests(cfg), slots=4, mesh=mesh)
     got, sched = _serve(cfg, _requests(cfg), slots=4, mesh=mesh,
                         spec_k=SPEC_K,

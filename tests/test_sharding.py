@@ -10,6 +10,7 @@ from repro.distributed.sharding import (
     ParamFactory, _cache_needs_seq_shard, make_rules, resolve_pspec,
     tree_pspecs,
 )
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tfm
 
 
@@ -22,7 +23,7 @@ class _FakeMesh:
 
 @pytest.fixture(scope="module")
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1))
 
 
 def test_rules_head_vs_ffn_mode(mesh11):
@@ -35,7 +36,7 @@ def test_rules_head_vs_ffn_mode(mesh11):
 
 
 def test_resolve_pspec_divisibility():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     rules = {"heads": "model", "embed": None, "batch": "data"}
     # 1-way axes always divide
     assert resolve_pspec(("batch", None, "heads"), (4, 7, 16), mesh, rules) \
@@ -149,7 +150,7 @@ def test_jit_train_step_on_1x1_mesh(key):
     from repro.data.tokens import synthetic_token_batch
     import dataclasses
     cfg = smoke_variant(get_config("olmo-1b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     rules = make_rules(cfg, mesh=mesh)
     tc = TrainConfig(total_steps=10, warmup_steps=1)
     pspecs, _ = param_pspecs(cfg, mesh, rules)

@@ -106,9 +106,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 from repro.configs import get_config, smoke_variant
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import lower_step
 from repro.configs.base import TrainConfig
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2))
 for arch in ("olmo-1b", "deepseek-moe-16b", "mamba2-130m",
              "recurrentgemma-2b"):
     cfg = smoke_variant(get_config(arch))

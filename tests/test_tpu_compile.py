@@ -1,0 +1,77 @@
+"""Native (Mosaic) compiles of the serving kernels for a described TPU v5e.
+
+Interpret mode accepts block shapes that the chip's compiler refuses, so
+these tests lower ``paged_attention_pallas(..., interpret=False)`` at real
+model widths for a v5e chip that is described, not attached, and assert
+that the compiled program holds the kernel (``tpu_custom_call``).  Nothing
+runs; each compile takes a second or two.
+
+The topology is described inside a module fixture, never at import or in
+a ``skipif``/``parametrize`` argument: only one process at a time may load
+the TPU library, and the test workers all import this module.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_attn.kernel import paged_attention_pallas
+
+PS = 16                                  # served page size
+SLOTS = 8
+MAX_LEN = 576                            # 512-token prompt + 64 new tokens
+SPEC_K = 4
+CHUNK = 256
+
+# name -> (Hkv, G, D, window, softcap), the arch's published widths
+WIDTHS = {
+    "qwen2-1.5b": (2, 6, 128, 0, 0.0),
+    "gemma2-2b": (4, 2, 256, 4096, 50.0),
+}
+# the three serve shapes: (slots B, query tokens T)
+SHAPES = {"decode": (SLOTS, 1), "verify": (SLOTS, SPEC_K + 1),
+          "chunk": (1, CHUNK)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2.  Its compiles stay out of the
+    persistent compilation cache, which cannot read them back without a
+    chip."""
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:              # no TPU compiler installed
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cached)
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+@pytest.mark.parametrize("arch", list(WIDTHS))
+def test_paged_attention_compiles_natively(one_chip, arch, shape):
+    Hkv, G, D, window, softcap = WIDTHS[arch]
+    B, T = SHAPES[shape]
+    n = -(-MAX_LEN // PS)
+    P = SLOTS * n
+
+    def spec(shp, dtype):
+        return jax.ShapeDtypeStruct(shp, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda q, k, v, pos, rows, qpos: paged_attention_pallas(
+        q, k, v, pos, rows, qpos, window=window, softcap=softcap,
+        interpret=False))
+    compiled = fn.lower(
+        spec((B, T, Hkv, G, D), jnp.bfloat16),
+        spec((P, PS, Hkv, D), jnp.bfloat16),
+        spec((P, PS, Hkv, D), jnp.bfloat16),
+        spec((P, PS), jnp.int32), spec((B, n), jnp.int32),
+        spec((B, T), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -6,6 +6,7 @@ import numpy as np
 
 from repro.configs import TrainConfig, get_config, smoke_variant
 from repro.data.tokens import synthetic_token_batch
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step_fn
 from repro.models import transformer as tfm
 from repro.optim import make_optimizer
@@ -56,9 +57,8 @@ def test_contribution_gate_changes_forward_and_is_identityish_at_init(key):
 
 
 def test_fsdp_rules_shard_embed_dim():
-    import jax as j
     from repro.distributed.sharding import make_rules
-    mesh = j.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     r_act = make_rules(get_config("olmo-1b"), mesh=mesh)
     r_par = make_rules(get_config("olmo-1b"), mesh=mesh, fsdp=True)
     assert r_act["embed"] is None
