@@ -68,18 +68,29 @@ def stacked_param_specs(cfg: ModelConfig):
 
 
 def forward(params, cfg: ModelConfig, batch: Dict[str, jax.Array]) -> jax.Array:
-    """batch: precip (B,T,P), dist (B,P), target_day (B,P) -> qhat (B,)."""
+    """batch: precip (B,T,P), dist (B,P), target_day (B,P) -> qhat (B,).
+
+    Each of the paper's blocks runs in a ``jax.named_scope`` (``pixcon``,
+    ``partition``, ``spatial``, ``temporal``): the names ride the HLO
+    ``op_name`` of its operations, through vmap and the backward pass,
+    so a device trace splits the step by block."""
     dc = cfg.domst
     precip = batch["precip"]
     if dc.use_pixcon:
-        x, w = pixcon_block(params["pixcon"], dc.pixcon, precip,
-                            batch["dist"], batch["target_day"])
-        parts, _ = partition_pixels(x, w, dc.num_heads)
+        with jax.named_scope("pixcon"):
+            x, w = pixcon_block(params["pixcon"], dc.pixcon, precip,
+                                batch["dist"], batch["target_day"])
+        with jax.named_scope("partition"):
+            parts, _ = partition_pixels(x, w, dc.num_heads)
     else:
-        parts = static_partition(precip, dc.num_heads)
-    feats = spatial_block(params["spatial"], dc, parts)
-    qhat = temporal_block(params["temporal"], dc, feats,
-                          batch["target_day"] if dc.use_target_day else None)
+        with jax.named_scope("partition"):
+            parts = static_partition(precip, dc.num_heads)
+    with jax.named_scope("spatial"):
+        feats = spatial_block(params["spatial"], dc, parts)
+    with jax.named_scope("temporal"):
+        qhat = temporal_block(params["temporal"], dc, feats,
+                              batch["target_day"] if dc.use_target_day
+                              else None)
     return qhat
 
 

@@ -40,6 +40,8 @@ from typing import Dict, Iterator, Optional, Protocol, runtime_checkable
 
 import numpy as np
 
+from repro.obs import profiler
+
 
 @runtime_checkable
 class DataSource(Protocol):
@@ -89,7 +91,10 @@ class ShardedLoader:
         return range(self.cursor, self.cursor + int(self.num_steps))
 
     def _place(self, step: int):
-        return self.engine.place_batch(self.source.host_batch(step))
+        # the producer's work on either path: gather and stack the host
+        # batch, then device_put it (a span only inside a profile window)
+        with profiler.annotate("train.loader_produce"):
+            return self.engine.place_batch(self.source.host_batch(step))
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         if self.prefetch <= 0:                 # synchronous reference path
@@ -129,7 +134,8 @@ class ShardedLoader:
         t.start()
         try:
             while True:
-                tag, item = q.get()
+                with profiler.annotate("train.loader_wait"):
+                    tag, item = q.get()
                 if tag is self._DONE:
                     return
                 if tag is self._ERR:

@@ -143,5 +143,6 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, TG, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_rows, qh, qrow, k_pool, v_pool, kp3)
     return out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)

@@ -82,11 +82,9 @@ def _make_mesh(name: str):
 def _timed(loader, registry: MetricRegistry):
     """Iterate ``loader`` while measuring, per step, the host-side wait
     for the next batch (``train.loader_wait_s`` — nonzero means the step
-    outran the input pipeline's prefetch) and the wall-clock of the loop
-    body (``train.step_s`` — dispatch plus whatever sync the body does).
-    The last wait also lands in the ``train.loader_wait_last_s`` gauge."""
+    outran the input pipeline's prefetch).  The last wait also lands in
+    the ``train.loader_wait_last_s`` gauge."""
     wait_h = registry.histogram("train.loader_wait_s")
-    step_h = registry.histogram("train.step_s")
     wait_g = registry.gauge("train.loader_wait_last_s")
     it = iter(loader)
     while True:
@@ -99,18 +97,14 @@ def _timed(loader, registry: MetricRegistry):
         wait_h.record(now - t)
         wait_g.set(now - t)
         yield batch
-        step_h.record(time.perf_counter() - now)
 
 
 def _train_metrics(registry: MetricRegistry) -> dict:
-    """Step-timing summary for the result JSON (empty before any step)."""
-    step_h = registry.histogram("train.step_s")
+    """Loader-wait summary for the result JSON (empty before any step)."""
     wait_h = registry.histogram("train.loader_wait_s")
-    if not step_h.count:
+    if not wait_h.count:
         return {}
-    return {"step_p50_s": round(step_h.quantile(50), 6),
-            "step_p99_s": round(step_h.quantile(99), 6),
-            "loader_wait_p99_s": round(wait_h.quantile(99), 6),
+    return {"loader_wait_p99_s": round(wait_h.quantile(99), 6),
             "loader_wait_s": round(wait_h.sum, 6)}
 
 
@@ -301,8 +295,7 @@ def build_parser() -> argparse.ArgumentParser:
                     help="restore a TrainState checkpoint before training "
                          "(the loader resumes the batch stream at its step)")
     ap.add_argument("--metrics-out", default="",
-                    help="dump the metric registry as JSONL (per-step "
-                         "timing histogram train.step_s, loader-wait "
+                    help="dump the metric registry as JSONL (loader-wait "
                          "histogram/gauge, metered loss)")
     ap.add_argument("--profile-dir", default="",
                     help="open a jax.profiler trace window over the "

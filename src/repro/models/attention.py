@@ -388,9 +388,10 @@ def decode_attention(params, cfg: ModelConfig, x: jax.Array, cache: KVCache,
     L = cache.k.shape[1]
     slot = pos % L                                              # (B,) ring slots
     bidx = jnp.arange(B)
-    new_k = cache.k.at[bidx, slot].set(k_new[:, 0].astype(cache.k.dtype))
-    new_v = cache.v.at[bidx, slot].set(v_new[:, 0].astype(cache.v.dtype))
-    new_pos = cache.pos.at[bidx, slot].set(pos)
+    with jax.named_scope("kv_write"):
+        new_k = cache.k.at[bidx, slot].set(k_new[:, 0].astype(cache.k.dtype))
+        new_v = cache.v.at[bidx, slot].set(v_new[:, 0].astype(cache.v.dtype))
+        new_pos = cache.pos.at[bidx, slot].set(pos)
     new_cache = KVCache(new_k, new_v, new_pos)
     out = attend_cached(params, cfg, q, new_cache.k, new_cache.v,
                         new_cache.pos, pos[:, None], window=window)
@@ -520,12 +521,13 @@ def paged_decode_attention(params, cfg: ModelConfig, x: jax.Array,
         jnp.atleast_1d(jnp.asarray(position, jnp.int32)), (B,))
     q, k_new, v_new = qkv_project(params, cfg, x, pos[:, None])
     P, ps = cache.k.shape[0], cache.k.shape[1]
-    phys, off, ok = _page_coords(page_rows, pos, ps, P, extra_ok=active)
-    new_k = cache.k.at[phys, off].set(k_new[:, 0].astype(cache.k.dtype),
-                                      mode="drop")
-    new_v = cache.v.at[phys, off].set(v_new[:, 0].astype(cache.v.dtype),
-                                      mode="drop")
-    new_pos = cache.pos.at[phys, off].set(pos, mode="drop")
+    with jax.named_scope("kv_write"):
+        phys, off, ok = _page_coords(page_rows, pos, ps, P, extra_ok=active)
+        new_k = cache.k.at[phys, off].set(k_new[:, 0].astype(cache.k.dtype),
+                                          mode="drop")
+        new_v = cache.v.at[phys, off].set(v_new[:, 0].astype(cache.v.dtype),
+                                          mode="drop")
+        new_pos = cache.pos.at[phys, off].set(pos, mode="drop")
     new_cache = PagedKVCache(new_k, new_v, new_pos)
     out = paged_attend(params, cfg, q, new_cache, page_rows, pos[:, None],
                        window=window)
@@ -560,12 +562,13 @@ def paged_multitok_attention(params, cfg: ModelConfig, x: jax.Array,
     rows = jnp.broadcast_to(page_rows[:, None, :],
                             (B, T, page_rows.shape[-1]))
     extra = None if active is None else active[:, None]
-    phys, off, ok = _page_coords(rows, qpos, ps, P, extra_ok=extra)
-    new_k = cache.k.at[phys, off].set(k_new.astype(cache.k.dtype),
-                                      mode="drop")
-    new_v = cache.v.at[phys, off].set(v_new.astype(cache.v.dtype),
-                                      mode="drop")
-    new_pos = cache.pos.at[phys, off].set(qpos, mode="drop")
+    with jax.named_scope("kv_write"):
+        phys, off, ok = _page_coords(rows, qpos, ps, P, extra_ok=extra)
+        new_k = cache.k.at[phys, off].set(k_new.astype(cache.k.dtype),
+                                          mode="drop")
+        new_v = cache.v.at[phys, off].set(v_new.astype(cache.v.dtype),
+                                          mode="drop")
+        new_pos = cache.pos.at[phys, off].set(qpos, mode="drop")
     new_cache = PagedKVCache(new_k, new_v, new_pos)
     out = paged_attend(params, cfg, q, new_cache, page_rows, qpos,
                        window=window)

@@ -194,53 +194,66 @@ def embed_inputs(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
 # ---------------------------------------------------------------------------
 # Layer application (full sequence)
 # ---------------------------------------------------------------------------
+# Named scopes: a layer's token mixer runs under ``attn`` (or its kind,
+# ``recurrent`` / ``ssm``), its FFN under ``mlp``, cache and page writes
+# under ``kv_write`` (models/attention.py), the final norm, projection and
+# sampling under ``logits``.  They ride the HLO op_name only: a device
+# trace splits a step by layer part, and the compiled code is unchanged.
+def _mixer_scope(kind: LayerKind) -> str:
+    return "attn" if kind in (ATTN_GLOBAL, ATTN_LOCAL) else kind
+
+
 def _apply_ffn(lp, cfg: ModelConfig, x: jax.Array, ffn: str):
     if ffn == "none":
         return x, jnp.zeros((), jnp.float32)
-    h = apply_norm(lp["norm2"], cfg.norm, x)
-    if ffn == "dense":
-        out = mlp_mod.mlp_block(lp["ffn"], cfg, h)
-        aux = jnp.zeros((), jnp.float32)
-    else:
-        out, aux = moe_mod.moe_block_auto(lp["ffn"], cfg, h)
-    if cfg.post_norms:
-        out = apply_norm(lp["post_norm2"], cfg.norm, out)
-    return x + out, aux
+    with jax.named_scope("mlp"):
+        h = apply_norm(lp["norm2"], cfg.norm, x)
+        if ffn == "dense":
+            out = mlp_mod.mlp_block(lp["ffn"], cfg, h)
+            aux = jnp.zeros((), jnp.float32)
+        else:
+            out, aux = moe_mod.moe_block_auto(lp["ffn"], cfg, h)
+        if cfg.post_norms:
+            out = apply_norm(lp["post_norm2"], cfg.norm, out)
+        return x + out, aux
 
 
 def apply_layer(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
                 ffn: str, *, collect_cache: bool = False,
                 max_len: int = 0):
     """Full-sequence layer.  Returns (x, aux, cache_entry_or_None)."""
-    h = apply_norm(lp["norm1"], cfg.norm, x)
-    cache_entry = None
-    if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-        if collect_cache:
-            out, (k, v) = attn_mod.attention_block(
-                lp["attn"], cfg, h, kind=kind, return_kv=True)
-            L = attn_mod.cache_length(cfg, kind, max_len)
-            empty = attn_mod.init_kv_cache(
-                x.shape[0], L, cfg.num_kv_heads, cfg.resolved_head_dim(),
-                dtype=x.dtype)
-            cache_entry = attn_mod.fill_cache_from_prefill(empty, k, v)
+    with jax.named_scope(_mixer_scope(kind)):
+        h = apply_norm(lp["norm1"], cfg.norm, x)
+        cache_entry = None
+        if kind in (ATTN_GLOBAL, ATTN_LOCAL):
+            if collect_cache:
+                out, (k, v) = attn_mod.attention_block(
+                    lp["attn"], cfg, h, kind=kind, return_kv=True)
+                L = attn_mod.cache_length(cfg, kind, max_len)
+                empty = attn_mod.init_kv_cache(
+                    x.shape[0], L, cfg.num_kv_heads, cfg.resolved_head_dim(),
+                    dtype=x.dtype)
+                with jax.named_scope("kv_write"):
+                    cache_entry = attn_mod.fill_cache_from_prefill(empty,
+                                                                   k, v)
+            else:
+                out = attn_mod.attention_block(lp["attn"], cfg, h, kind=kind)
+        elif kind == RECURRENT:
+            if collect_cache:
+                out, cache_entry = rglru_mod.rglru_block(
+                    lp["rec"], cfg, h, return_state=True)
+            else:
+                out = rglru_mod.rglru_block(lp["rec"], cfg, h)
+        elif kind == SSM:
+            if collect_cache:
+                out, cache_entry = ssm_mod.ssm_block(
+                    lp["ssm"], cfg, h, return_state=True)
+            else:
+                out = ssm_mod.ssm_block(lp["ssm"], cfg, h)
         else:
-            out = attn_mod.attention_block(lp["attn"], cfg, h, kind=kind)
-    elif kind == RECURRENT:
-        if collect_cache:
-            out, cache_entry = rglru_mod.rglru_block(
-                lp["rec"], cfg, h, return_state=True)
-        else:
-            out = rglru_mod.rglru_block(lp["rec"], cfg, h)
-    elif kind == SSM:
-        if collect_cache:
-            out, cache_entry = ssm_mod.ssm_block(
-                lp["ssm"], cfg, h, return_state=True)
-        else:
-            out = ssm_mod.ssm_block(lp["ssm"], cfg, h)
-    else:
-        raise ValueError(kind)
-    if cfg.post_norms:
-        out = apply_norm(lp["post_norm1"], cfg.norm, out)
+            raise ValueError(kind)
+        if cfg.post_norms:
+            out = apply_norm(lp["post_norm1"], cfg.norm, out)
     x = x + out
     x, aux = _apply_ffn(lp, cfg, x, ffn)
     return x, aux, cache_entry
@@ -252,10 +265,11 @@ def _apply_layer_step(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
     residual -> FFN) for the one-token and chunked paths; ``mixer_fn(lp,
     kind, h) -> (out, new_cache_entry)`` supplies the cached
     attention/recurrent step."""
-    h = apply_norm(lp["norm1"], cfg.norm, x)
-    out, new_entry = mixer_fn(lp, kind, h)
-    if cfg.post_norms:
-        out = apply_norm(lp["post_norm1"], cfg.norm, out)
+    with jax.named_scope(_mixer_scope(kind)):
+        h = apply_norm(lp["norm1"], cfg.norm, x)
+        out, new_entry = mixer_fn(lp, kind, h)
+        if cfg.post_norms:
+            out = apply_norm(lp["post_norm1"], cfg.norm, out)
     x = x + out
     x, _ = _apply_ffn(lp, cfg, x, ffn)
     return x, new_entry
@@ -461,9 +475,10 @@ def _decode_walk(params, cfg: ModelConfig, x: jax.Array, caches, layer_fn):
 
 
 def _finish_logits(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = apply_norm(params["final_norm"], cfg.norm, x)
-    return unembed(params["embed"], x, tie=cfg.tie_embeddings,
-                   cap=cfg.logit_softcap, real_vocab=cfg.vocab_size)
+    with jax.named_scope("logits"):
+        x = apply_norm(params["final_norm"], cfg.norm, x)
+        return unembed(params["embed"], x, tie=cfg.tie_embeddings,
+                       cap=cfg.logit_softcap, real_vocab=cfg.vocab_size)
 
 
 def decode_step(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
@@ -666,6 +681,7 @@ def scatter_prefill_paged(cfg: ModelConfig, paged_caches, prefill_caches,
     differ structurally, so this walks the stack plan entry by entry."""
     prefix, pat, n_rep, suffix = stack_plan(cfg)
 
+    @jax.named_scope("kv_write")
     def one(kind, pooled, fresh, stacked: bool):
         if kind in (ATTN_GLOBAL, ATTN_LOCAL):
             if stacked:                     # leading layer-repetition axis

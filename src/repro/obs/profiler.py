@@ -7,12 +7,13 @@ Two pieces, both inert unless a profile window is open:
     captures a device trace (open the run directory in TensorBoard's
     profile plugin or ui.perfetto.dev);
   * :func:`annotate` — a ``jax.profiler.TraceAnnotation`` scope the
-    engines place around prefill/decode/verify/restore DISPATCH, so the
-    host-side phase names line up with device timelines on real
-    hardware.  When no window is active (the common case, and always in
-    unit tests) it returns a null context and costs one attribute read —
-    annotation can never perturb numerics or show up in the digest
-    parity tests.
+    engines place around prefill/decode/verify/restore DISPATCH, the
+    loader around batch assembly and its wait, and ``obs.trace.Tracer``
+    around each scheduler span, so the host-side phase names line up
+    with device timelines on real hardware.  When no window is active
+    (the common case, and always in unit tests) it returns a null
+    context and costs one attribute read — annotation can never perturb
+    numerics or show up in the digest parity tests.
 """
 from __future__ import annotations
 
@@ -26,11 +27,17 @@ def active() -> bool:
 
 
 def start(profile_dir: str) -> None:
-    """Open a ``jax.profiler`` trace window writing to ``profile_dir``."""
+    """Open a ``jax.profiler`` trace window writing to ``profile_dir``:
+    device operations and host annotations (host tracer level 2), with
+    no per-call Python events (the Python tracer's cost lands on every
+    host phase the trace is meant to time)."""
     global _active
     import jax
 
-    jax.profiler.start_trace(profile_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(profile_dir, profiler_options=opts)
     _active = True
 
 
@@ -59,11 +66,12 @@ def profile(profile_dir=None):
         stop()
 
 
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` while a profile window is
-    open, else a null context."""
+def annotate(name: str, **args):
+    """``jax.profiler.TraceAnnotation(name, **args)`` while a profile
+    window is open, else a null context.  ``args`` become the host
+    event's stats in the trace."""
     if not _active:
         return nullcontext()
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)

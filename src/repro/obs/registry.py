@@ -20,7 +20,7 @@ training step timings, loader-wait gauges.  Code that used to keep flat
     launchers write and ``scripts/ci_smoke.py obs`` validates;
   * NAMING: dotted lowercase paths, ``<subsystem>.<metric>[_<unit>]``
     (``serve.ttft_s``, ``serve.decode_gap_s``, ``sched.run.<counter>``,
-    ``train.step_s``).  Units ride the suffix (``_s`` seconds,
+    ``train.loader_wait_s``).  Units ride the suffix (``_s`` seconds,
     ``_tokens``, ``_pages``) so downstream tooling never guesses.
 
 Everything here is pure host-side Python: recording a metric can never

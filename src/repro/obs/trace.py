@@ -25,6 +25,13 @@ span boundary from the SAME clock read, so span-derived request metrics
 (:func:`derive_request_metrics`) agree with the legacy ``sched.ttft``
 dict to float precision, not merely "within a millisecond".
 
+While a ``jax.profiler`` window is open (``repro.obs.profiler.active()``)
+each ``sched`` span is also a ``TraceAnnotation`` named ``serve.<name>``
+carrying the span's begin arguments, so the device trace names every
+scheduler phase on its own clock.  The ``rid<N>`` tracks are not
+mirrored: their spans overlap and belong to no thread.  With no window
+open this costs one boolean read per span.
+
 A disabled tracer (``Tracer(enabled=False)``) drops everything at the
 ``begin``/``instant`` call site; tracing is pure host-side bookkeeping
 either way, so emitted token streams are bit-identical with tracing on
@@ -37,6 +44,13 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
+from repro.obs import profiler
+
+#: the track whose spans are mirrored onto the device trace, and the
+#: prefix their annotations take there
+DEVICE_TRACK = "sched"
+DEVICE_PREFIX = "serve."
+
 
 class Tracer:
     """Span collector with begin/end handles and a Chrome-trace export."""
@@ -48,6 +62,7 @@ class Tracer:
         self._t0 = time.perf_counter()
         self._events: List[Dict[str, Any]] = []
         self._open: Dict[int, Tuple[str, str, float, Dict[str, Any]]] = {}
+        self._annotations: Dict[int, Any] = {}
         self._next = 0
 
     # -- clocks ------------------------------------------------------------
@@ -76,12 +91,18 @@ class Tracer:
         h = self._next
         self._next += 1
         self._open[h] = (name, tid, self._us(at), args)
+        if profiler.active() and tid == DEVICE_TRACK:
+            a = profiler.annotate(DEVICE_PREFIX + name, **args)
+            a.__enter__()
+            self._annotations[h] = a
         return h
 
     def end(self, handle: Optional[int], at: Optional[float] = None,
             **extra) -> None:
         if handle is None or not self.enabled:
             return
+        if self._annotations:
+            self._exit_annotation(handle)
         ent = self._open.pop(handle, None)
         if ent is None:
             return
@@ -91,6 +112,19 @@ class Tracer:
         self._push({"name": name, "ph": "X", "ts": ts,
                     "dur": max(self._us(at) - ts, 0.0), "tid": tid,
                     "args": args})
+
+    def _exit_annotation(self, handle: int) -> None:
+        a = self._annotations.pop(handle, None)
+        if a is not None:
+            a.__exit__(None, None, None)
+
+    def abandon_open(self) -> None:
+        """Forget every span still open (a run cut short by an exception
+        inside a step), exiting its annotation; none of them is
+        recorded."""
+        for h in list(self._annotations):
+            self._exit_annotation(h)
+        self._open.clear()
 
     @contextmanager
     def span(self, name: str, tid: str = "sched", **args):

@@ -401,6 +401,7 @@ class InferenceEngine:
                     top_k=state.sample_top_k, top_p=state.sample_top_p,
                     rep_penalty=state.sample_rep)
 
+    @jax.named_scope("logits")        # sampling, beside the projection
     def _sample_one(self, state: InferenceState, logits: jax.Array,
                     slot: jax.Array, pos) -> jax.Array:
         """First-token emission for one slot (prefill / final chunk):
@@ -424,6 +425,7 @@ class InferenceEngine:
         return jax.lax.cond(state.sample_temp[slot] > 0, _go,
                             lambda: greedy)
 
+    @jax.named_scope("logits")        # sampling, beside the projection
     def _sample_all(self, state: InferenceState, logits: jax.Array,
                     positions: jax.Array, active=None):
         """All-slot emission for the fused decode: (tokens (S,), presence).

@@ -1121,7 +1121,8 @@ class Scheduler:
         toks = prompt[None, adm.cursor:adm.cursor + c]
         t0 = time.perf_counter()
         h = self.tracer.begin("prefill_chunk", at=t0, rid=r.rid,
-                              cursor=adm.cursor, tokens=int(c))
+                              cursor=adm.cursor, tokens=int(c),
+                              **self._page_work(1, [adm.cursor + c]))
         self.state, tok = self.engine.insert_chunk(
             self.state, {"tokens": toks}, adm.slot, adm.cursor)
         first = int(np.asarray(tok)[0])     # sync point ends the timing
@@ -1151,6 +1152,19 @@ class Scheduler:
         self.admission_order.append(r.rid)
         self._note_first(r)
         return True
+
+    def _page_work(self, rows: int, keys) -> Dict[str, int]:
+        """Span arguments of one paged-attention call: ``live_pages``, the
+        pages that hold the keys each served slot attends to (``keys``:
+        its cached tokens once the call has written its first input), and
+        ``walked_pages``, the pages the kernel's grid walks, ``rows`` x
+        pages per slot whatever the slots hold.  Empty when not paged."""
+        if not self.engine.paged:
+            return {}
+        ps = self.engine.page_size
+        return {"rows": rows,
+                "live_pages": sum(-(-int(k) // ps) for k in keys),
+                "walked_pages": rows * self.engine.pages_per_slot}
 
     # -- speculation -------------------------------------------------------
     def _spec_round(self, active: Dict[int, Request], mask: np.ndarray):
@@ -1218,6 +1232,7 @@ class Scheduler:
                 self.tracer.end(h)
             self._rid_open.clear()
             self.tracer.end(h_run)
+            self.tracer.abandon_open()      # phases an exception cut short
             self._drain_pool_events()
             self._fold_lifetime()
 
@@ -1359,8 +1374,11 @@ class Scheduler:
                     mask = np.zeros((self.engine.slots,), bool)
                     mask[list(active)] = True
                 t0 = time.perf_counter()
-                h_dec = self.tracer.begin("decode_step", at=t0,
-                                          slots=len(active))
+                h_dec = self.tracer.begin(
+                    "decode_step", at=t0, slots=len(active),
+                    **self._page_work(self.engine.slots, [
+                        len(r.prompt) + len(r.generated)
+                        for r in active.values()]))
                 if self.spec_k:
                     emitted, consumed = self._spec_round(active, mask)
                 else:

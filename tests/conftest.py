@@ -1,6 +1,7 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests must see the
 real single CPU device (the 512-device override is dryrun.py-only)."""
 import os
+import re
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -45,3 +46,17 @@ def rng():
 @pytest.fixture(scope="session")
 def key():
     return jax.random.key(0)
+
+
+@pytest.fixture(scope="session")
+def op_name_scopes():
+    """The path components of every op_name in lowered HLO text
+    (``.as_text(debug_info=True)``), looking through transformation
+    wrappers: ``vmap(transpose(jvp(temporal)))/while`` gives ``temporal``
+    and ``while`` among others."""
+    def scopes(text):
+        out = set()
+        for name in re.findall(r'loc\("([^"]*)"', text):
+            out.update(re.split(r"[/()]", name))
+        return out
+    return scopes

@@ -108,3 +108,22 @@ def test_stacked_step_isolates_watersheds(rng, key):
     d1 = sum(float(jnp.sum(jnp.abs(a[1] - b[1])))
              for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)))
     assert d0 == 0.0 and d1 > 0.0
+
+
+def test_stacked_step_names_each_block(rng, op_name_scopes):
+    """The stacked train step's op_names carry each block's named scope,
+    forward and backward, so a device trace splits the step by block."""
+    import re
+    from repro.train import Engine
+    cfg = get_config("domst")
+    tc = TrainConfig(learning_rate=1e-3, total_steps=10, warmup_steps=1)
+    eng = Engine.for_domst(cfg, tc, stacked=True)
+    state = eng.init_state(jax.random.key(0),
+                           domst.init_stacked(cfg, jax.random.key(0), 2))
+    b = {k: jnp.stack([v, v]) for k, v in _batch(rng).items()}
+    text = eng._get_jit(state, b).lower(state, b).as_text(debug_info=True)
+    assert {"pixcon", "partition", "spatial", "temporal"} \
+        <= op_name_scopes(text)
+    for scope in ("pixcon", "spatial", "temporal"):
+        assert re.search(rf'loc\("[^"]*transpose\(jvp\({scope}\)', text), \
+            scope                                   # the backward pass too

@@ -247,3 +247,35 @@ def test_train_cli_resume_roundtrip(tmp_path):
     # the resumed epoch of steps logs as epoch 1, not epoch 0
     assert "epoch 1 mean loss" in out2.stdout
     assert "epoch 0 mean loss" not in out2.stdout
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_loader_annotations_leave_batches_bit_identical(hydro, monkeypatch,
+                                                        prefetch):
+    """Inside a profile window the loader annotates each batch's
+    production and the consumer's wait; the batches are unchanged."""
+    from repro.obs import profiler
+    windows, ip = hydro
+    src = StackedSource(ip)
+    quiet = list(ShardedLoader(src, HostOnly, prefetch=prefetch,
+                               num_steps=5))
+    names = []
+
+    class Ann:
+        def __init__(self, name, **args):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    monkeypatch.setattr(profiler, "_active", True)
+    loud = list(ShardedLoader(src, HostOnly, prefetch=prefetch,
+                              num_steps=5))
+    for x, y in zip(quiet, loud):
+        for k in x:
+            np.testing.assert_array_equal(x[k], y[k])
+    assert names.count("train.loader_produce") == 5
+    assert names.count("train.loader_wait") == (5 + 1 if prefetch else 0)

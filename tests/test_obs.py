@@ -184,3 +184,86 @@ def test_tracing_parity(arch):
     for rid in out_on:
         assert out_on[rid] == out_off[rid], rid
     assert stream_digest(out_on) == stream_digest(out_off)
+
+
+# ---------------------------------------------------------------------------
+# Device-trace mirroring: inside a profile window each sched span is also a
+# TraceAnnotation ``serve.<name>`` carrying the span's arguments
+# ---------------------------------------------------------------------------
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that logs enters and
+    exits."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class Ann:
+            def __init__(self, name, **args):
+                self.name, self.args = name, args
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name, self.args))
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+        self.cls = Ann
+
+
+def test_tracer_mirrors_sched_spans_only_inside_a_window(monkeypatch):
+    from repro.obs import profiler
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    t = Tracer()
+    h = t.begin("iter")                          # no window: nothing mirrored
+    t.end(h)
+    assert ann.log == []
+    monkeypatch.setattr(profiler, "_active", True)
+    h_it = t.begin("iter")
+    h_q = t.begin("queued", tid="rid3", rid=3)   # rid tracks: never
+    h_dec = t.begin("decode_step", slots=2, live_pages=5)
+    t.end(h_dec)
+    t.end(h_q)
+    t.end(h_it)
+    assert ann.log == [
+        ("enter", "serve.iter", {}),
+        ("enter", "serve.decode_step", {"slots": 2, "live_pages": 5}),
+        ("exit", "serve.decode_step"), ("exit", "serve.iter")]
+    # a run cut short: the open spans' annotations close, nothing records
+    ann.log.clear()
+    n = len(t.events())
+    t.begin("iter")
+    t.begin("decode_step")
+    t.abandon_open()
+    assert [e[0] for e in ann.log] == ["enter", "enter", "exit", "exit"]
+    assert len(t.events()) == n
+
+
+def test_scheduler_annotates_one_per_sched_span_and_streams_match(
+        monkeypatch):
+    """With a window forced open, every ``sched`` span enters and exits
+    one ``serve.<name>`` annotation (the engine's dispatch annotations
+    come on top), and the token streams are those of an unannotated run."""
+    from repro.obs import profiler
+    cfg = smoke_variant(get_config("qwen2-1.5b"))
+    reqs = lambda: _requests(cfg, [PROMPT] * 4)          # noqa: E731
+    kw = dict(paged=True, page_size=4, prefill_chunk=4)
+    quiet, _ = _serve(cfg, reqs(), sched_kw={"tracer": Tracer()}, **kw)
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    monkeypatch.setattr(profiler, "_active", True)
+    tracer = Tracer()
+    loud, _ = _serve(cfg, reqs(), sched_kw={"tracer": tracer}, **kw)
+    assert loud == quiet
+    sched = [e for e in tracer.events()
+             if e["ph"] == "X" and e["tid"] == "sched"]
+    entered = [name for kind, name, *_ in ann.log if kind == "enter"]
+    exited = [name for kind, name, *_ in ann.log if kind == "exit"]
+    assert sorted(entered) == sorted(exited)
+    engine_sites = {"serve.insert", "serve.insert_chunk", "serve.decode",
+                    "serve.verify"}
+    from collections import Counter
+    mirrored = Counter(n for n in entered if n not in engine_sites)
+    assert mirrored == Counter("serve." + e["name"] for e in sched)
+    assert not any(n.startswith("serve.rid") or n in (
+        "serve.queued", "serve.prefill") for n in entered)

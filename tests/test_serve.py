@@ -755,3 +755,43 @@ def test_train_ckpt_serve_cli_roundtrip(tmp_path):
     ev = eng.eval_step(state, eng.place_batch(stacked_test_batch(windows)))
     np.testing.assert_allclose(np.asarray(rec["nse"]), np.asarray(ev["nse"]),
                                rtol=1e-4, atol=1e-5)
+
+
+def test_paged_decode_names_each_layer_part(op_name_scopes):
+    """The fused paged decode step's op_names carry ``attn``, ``mlp``,
+    ``kv_write`` and ``logits``: a device trace splits it by layer part."""
+    cfg = smoke_variant(get_config("qwen2-1.5b"))
+    eng = InferenceEngine(cfg, slots=2, dtype=jnp.float32, max_len=16,
+                          paged=True, page_size=4)
+    state = eng.init_state(T.init(cfg, jax.random.key(0)))
+    text = eng.lower_decode(state).as_text(debug_info=True)
+    assert {"attn", "mlp", "kv_write", "logits"} <= op_name_scopes(text)
+
+
+def test_paged_spans_count_live_and_walked_pages():
+    """``prefill_chunk`` and ``decode_step`` spans carry the paged
+    kernel's work: live pages (per served slot, its cached tokens over
+    the page size, rounded up) and walked pages (rows x pages per slot),
+    here against counts made by hand.  Slots 2, pages of 4, 3 pages a
+    slot, chunks of 4; prompts of 5 and 9 tokens, 3 tokens each."""
+    from repro.obs import Tracer
+    cfg = smoke_variant(get_config("qwen2-1.5b"))
+    tracer = Tracer()
+    _serve(cfg, _requests(cfg, [5, 9], gen=3), slots=2, max_len=12,
+           paged=True, page_size=4, prefill_chunk=4,
+           sched_kw={"tracer": tracer})
+    got = [(e["name"], e["args"]["rows"], e["args"]["live_pages"],
+            e["args"]["walked_pages"]) for e in tracer.events()
+           if e["ph"] == "X" and e["name"] in ("prefill_chunk",
+                                               "decode_step")]
+    assert got == [
+        ("prefill_chunk", 1, 1, 3),     # A tokens 0-3: 4 keys
+        ("prefill_chunk", 1, 2, 3),     # A token 4: 5 keys
+        ("decode_step", 2, 2, 6),       # A: 5 + 1 keys
+        ("prefill_chunk", 1, 1, 3),     # B tokens 0-3
+        ("decode_step", 2, 2, 6),       # A: 7 keys; A is done
+        ("prefill_chunk", 1, 2, 3),     # B tokens 4-7: 8 keys
+        ("prefill_chunk", 1, 3, 3),     # B token 8: 9 keys
+        ("decode_step", 2, 3, 6),       # B: 10 keys
+        ("decode_step", 2, 3, 6),       # B: 11 keys; B is done
+    ]

@@ -10,6 +10,8 @@ The topology is described inside a module fixture, never at import or in
 a ``skipif``/``parametrize`` argument: only one process at a time may load
 the TPU library, and the test workers all import this module.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -74,4 +76,9 @@ def test_paged_attention_compiles_natively(one_chip, arch, shape):
         spec((P, PS, Hkv, D), jnp.bfloat16),
         spec((P, PS), jnp.int32), spec((B, n), jnp.int32),
         spec((B, T), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's instruction carries the pallas_call's name, which is
+    # how a device trace names it (``bench/kernels.py``: ^paged_attention)
+    assert re.search(r"%paged_attention(\.\d+)? = [^\n]*tpu_custom_call",
+                     text)
