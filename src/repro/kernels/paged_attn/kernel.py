@@ -3,28 +3,37 @@
 Fuses the three steps the lax serving path does separately — per-slot
 page-table lookup, KV page gather, online-softmax attend — into one
 kernel, so no (B, n*ps, Hkv, D) gathered copy of the cache ever
-materializes.  One grid covers the three serve shapes: single-token
+materializes.  One kernel covers the three serve shapes: single-token
 decode (T=1), speculative verify (T=k+1), and chunked prefill (T=chunk).
 
-Grid: (B, n) — the innermost axis walks a slot's page row.  The page
-table (B, n) rides in scalar-prefetch memory (SMEM) so the K/V/pos
-BlockSpec index maps can translate logical page j of slot b into the
-physical pool page ``tab[b, j]`` before the block is fetched — this is
-the "lookup fused into the gather" half; unassigned entries (-1) clamp
-to page 0 and are masked in-kernel.  Each K/V block is one whole page
-with all ``Hkv`` heads, so every page is fetched once per slot; the
-kernel loops the heads statically.  The online-softmax state lives in
-f32 VMEM scratch keyed by (head, flattened T*G query row); the output
-block index repeats across the page walk and is committed on the last
-page.
+Grid: (B,), one step per slot row.  A row walks only its live pages:
+the wrapper derives each row's extent, the logical pages
+``[first, end)`` its queries can see, from ``qpos`` (and the window),
+and an inactive row gets an empty extent.  The extents and the page
+table ride in scalar-prefetch memory (SMEM).  The K, V and pos pools
+stay in HBM (``memory_space=pl.ANY``); the kernel loops over the row's
+blocks of ``ppb`` pages (``pages_per_block``: 128 keys at 16-token
+pages) with a dynamic trip count, and copies each block's pages into a
+VMEM buffer by one DMA per page (``pool[tab[b, j]]``), double-buffered:
+block i+1's copies are in flight while block i computes.  Blocks are
+aligned to multiples of ``ppb`` in the row, so the walk covers the
+extent rounded out to whole blocks (``walked_pages`` counts exactly
+that).  A page slot of a block that lies outside the extent or is
+unassigned (-1) is never copied: its V rows are zeroed and its positions
+read as -1, so stale VMEM never reaches ``p @ v``.
 
-Mosaic block layout: the last two dims of every block must be (8, 128)
-multiples or the whole array dims.  So the wrapper hands the kernel
-q and out as (B, Hkv, T*G, D) (the GQA group flattened OUTSIDE the
-kernel, where a G that is no multiple of 8 costs nothing), per-row
-query positions as (B, T*G, 1), and the pos pool as (P, 1, ps); the K/V
-pools keep their (P, ps, Hkv, D) serving layout, whose block
-(1, ps, Hkv, D) spans the last two dims whole.
+The online-softmax state lives in f32 VMEM scratch keyed by (head,
+flattened T*G query row); the kernel loops the heads statically, and
+each block feeds the MXU one (T*G, ppb*ps) score tile per head.
+
+Mosaic layout: the last two dims of every VMEM block must be (8, 128)
+multiples or the whole array dims, and a DMA moves whole lane tiles.  So
+the wrapper hands the kernel q and out as (B, Hkv, T*G, D) (the GQA
+group flattened OUTSIDE the kernel, where a G that is no multiple of 8
+costs nothing), per-row query positions as (B, T*G, 1), and the pos
+pool as lane-dense (R, 128) rows (``_pos_rows``); a page's DMA fetches
+its whole row, and the kernel rotates the page's lanes into place in
+the block's (1, ppb*ps) key-position row.
 
 Masking is pure position metadata, identical to the lax
 ``attend_cached`` path: an entry is attendable iff its page is assigned,
@@ -44,98 +53,224 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128                     # a vector register's lanes: keys per block
 
 
-def _kernel(tab_ref, q_ref, qp_ref, k_ref, v_ref, kp_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, Hkv: int, n: int, window: int,
+def pages_per_block(ps: int, n: int) -> int:
+    """Pages the kernel copies and scores per block: 128 keys' worth, at
+    least one page, at most the row's ``n``."""
+    return max(1, min(n, LANES // ps))
+
+
+def _pos_width(ps: int) -> int:
+    """Lanes a page's positions take in the lane-dense pos rows: ``ps``
+    rounded up to a power of two below 128 lanes (so whole pages pack a
+    row), or to whole rows above."""
+    if ps <= LANES:
+        return 1 << (ps - 1).bit_length()
+    return -(-ps // LANES) * LANES
+
+
+def page_extent(qmin, qmax, *, ps: int, n: int, window: int = 0):
+    """Logical pages ``[first, end)`` a row's queries at positions
+    ``qmin..qmax`` can see: up to the page of ``qmax``, and, under a
+    window, from the page of the oldest key ``qmin`` may attend to.
+    Works on numpy values (the scheduler's counts) and traced jax arrays
+    (the kernel's wrapper) alike."""
+    xp = jnp if isinstance(qmax, jax.Array) else np
+    first = xp.maximum((qmin - window + 1) // ps, 0) if window \
+        else xp.zeros_like(qmax)
+    return first, xp.minimum(qmax // ps + 1, n)
+
+
+def walked_pages(first, end, *, ppb: int, n: int):
+    """Page slots the kernel walks for extents ``[first, end)``: each
+    extent rounded out to whole ``ppb``-page blocks of the row, capped at
+    its ``n`` pages; an empty extent walks none."""
+    xp = jnp if isinstance(end, jax.Array) else np
+    lo = first // ppb * ppb
+    hi = xp.minimum(-(-end // ppb) * ppb, n)
+    return xp.where(end > first, hi - lo, 0)
+
+
+def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
+            kp_hbm, o_ref, kbuf, vbuf, pbuf, sem, m_ref, l_ref, acc_ref, *,
+            Hkv: int, n: int, ps: int, ppb: int, window: int,
             softcap: float):
     b = pl.program_id(0)
-    j = pl.program_id(1)                                        # page step
+    first, end = first_ref[b], end_ref[b]
+    blk0 = first // ppb
+    nblk = jnp.where(end > first, (end + ppb - 1) // ppb - blk0, 0)
+    psw = _pos_width(ps)
+    rpp = max(1, psw // LANES)              # pos rows per page
+    ppr = max(1, LANES // psw)              # pages per pos row
+    K = ppb * ps                            # keys per block
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def pages(i):
+        """Each page slot of block ``i``: (slot index, physical page,
+        whether it is copied)."""
+        for s in range(ppb):
+            j = (blk0 + i) * ppb + s
+            page = tab_ref[b, jnp.minimum(j, n - 1)]
+            yield s, page, (j >= first) & (j < end) & (page >= 0)
 
+    def copies(s, page, buf):
+        keys = pl.ds(s * ps, ps)
+        return (
+            pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, keys],
+                                  sem.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, keys],
+                                  sem.at[1, buf]),
+            pltpu.make_async_copy(kp_hbm.at[pl.ds(page * rpp // ppr, rpp)],
+                                  pbuf.at[buf, pl.ds(s * rpp, rpp)],
+                                  sem.at[2, buf]))
+
+    def fetch(i, buf):
+        for s, page, live in pages(i):
+            @pl.when(live)
+            def _copy():
+                for c in copies(s, page, buf):
+                    c.start()
+
+            @pl.when(jnp.logical_not(live))
+            def _blank():                   # stale VMEM must not reach p @ v
+                vbuf[buf, pl.ds(s * ps, ps)] = jnp.zeros(
+                    (ps,) + vbuf.shape[2:], vbuf.dtype)
+
+    def positions(i, buf):
+        """The block's key positions as one (1, K) row; -1 where a page
+        slot was not copied."""
+        rows = pbuf[buf]                                        # (ppb*rpp, 128)
+        if rpp > 1:                                             # ppb == 1
+            (_, _, live), = pages(i)
+            kp = jnp.concatenate([rows[r:r + 1] for r in range(rpp)],
+                                 axis=1)[:, :ps]
+            return jnp.where(live, kp, -1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        kp = jnp.full((1, LANES), -1, jnp.int32)
+        for s, page, live in pages(i):
+            # page ``page`` sits at lane (page % ppr) * psw of its row
+            shift = (s * ps - page % ppr * psw) % LANES
+            row = pltpu.roll(rows[s:s + 1], shift, 1)
+            keys = (lane >= s * ps) & (lane < s * ps + ps) & live
+            kp = jnp.where(keys, row, kp)
+        return kp[:, :K]
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     qp = qp_ref[0]                                              # (T*G, 1)
-    kp = kp_ref[0]                                              # (1, ps)
-    mask = (tab_ref[b, j] >= 0) & (kp >= 0) & (kp <= qp)        # (T*G, ps)
-    if window:
-        mask = mask & (qp - kp < window)
     D = q_ref.shape[-1]
 
-    for h in range(Hkv):
-        qf = q_ref[0, h].astype(jnp.float32)                    # (T*G, D)
-        k = k_ref[0, :, h, :].astype(jnp.float32)               # (ps, D)
-        v = v_ref[0, :, h, :].astype(jnp.float32)
-        s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s / np.sqrt(D)                                      # (T*G, ps)
-        if q_ref.dtype != jnp.float32:
-            # the lax path's score einsum runs in q.dtype before the f32
-            # cast; round through it so both paths see identical scores
-            s = s.astype(q_ref.dtype).astype(jnp.float32)
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        s = jnp.where(mask, s, NEG_INF)
+    @pl.when(nblk > 0)
+    def _prologue():
+        fetch(0, 0)
 
-        m_prev = m_ref[h]                                       # (T*G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[h] = m_new
+    def block(i, carry):
+        buf = i % 2
 
-    @pl.when(j == n - 1)
-    def _commit():
-        l = l_ref[...]                                          # (Hkv, T*G, 1)
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)
-        out = jnp.where(l > 0, out, 0.0)                        # no attendable key
-        o_ref[0] = out.astype(o_ref.dtype)
+        @pl.when(i + 1 < nblk)
+        def _prefetch():
+            fetch(i + 1, 1 - buf)
+
+        for s, page, live in pages(i):
+            @pl.when(live)
+            def _wait():
+                for c in copies(s, page, buf):
+                    c.wait()
+
+        kp = positions(i, buf)                                  # (1, K)
+        mask = (kp >= 0) & (kp <= qp)                           # (T*G, K)
+        if window:
+            mask = mask & (qp - kp < window)
+        for h in range(Hkv):
+            q = q_ref[0, h]                                     # (T*G, D)
+            k = kbuf[buf, :, h, :]                              # (K, D)
+            if q.dtype != k.dtype:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s / np.sqrt(D)
+            if q_ref.dtype != jnp.float32:
+                # the lax path's score einsum runs in q.dtype before the
+                # f32 cast; round through it so both paths see identical
+                # scores
+                s = s.astype(q_ref.dtype).astype(jnp.float32)
+            if softcap:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(mask, s, NEG_INF)
+
+            m_prev = m_ref[h]                                   # (T*G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            v = vbuf[buf, :, h, :].astype(jnp.float32)          # (K, D)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+
+    l = l_ref[...]                                              # (Hkv, T*G, 1)
+    out = acc_ref[...] / jnp.maximum(l, 1e-30)
+    out = jnp.where(l > 0, out, 0.0)                            # no attendable key
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
-def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos, *,
-                           window: int = 0, softcap: float = 0.0,
-                           interpret: bool = True):
+def _pos_rows(pos_pool):
+    """(P, ps) positions -> lane-dense (R, 128) rows a page's DMA can
+    fetch whole: each page padded with -1 to ``_pos_width`` lanes."""
+    P, ps = pos_pool.shape
+    psw = _pos_width(ps)
+    ppr = max(1, LANES // psw)
+    pos = jnp.pad(pos_pool, ((0, -P % ppr), (0, psw - ps)),
+                  constant_values=-1)
+    return pos.reshape(-1, LANES)
+
+
+def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
+                           active=None, *, window: int = 0,
+                           softcap: float = 0.0, interpret: bool = True):
     """q (B,T,Hkv,G,D); k/v pool (P,ps,Hkv,D); pos pool (P,ps);
     page_rows (B,n) physical page ids (-1 = unassigned); qpos (B,T)
-    absolute query positions -> (B,T,Hkv,G,D)."""
+    absolute query positions; ``active`` (B,) bool or None (all rows):
+    an inactive row walks no page and outputs 0 -> (B,T,Hkv,G,D)."""
     B, T, Hkv, G, D = q.shape
     P, ps = pos_pool.shape
     n = page_rows.shape[1]
     TG = T * G
+    ppb = pages_per_block(ps, n)
+    first, end = page_extent(qpos.min(axis=1), qpos.max(axis=1), ps=ps,
+                             n=n, window=window)
+    if active is not None:
+        end = jnp.where(active, end, first)
     qh = q.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, TG, D)
     qrow = jnp.repeat(qpos, G, axis=1)[:, :, None]              # row t*G+g
-    kp3 = pos_pool[:, None, :]
+    rpp = max(1, _pos_width(ps) // LANES)
 
-    def page(b, j, tab):
-        return jnp.maximum(tab[b, j], 0)
-
-    kern = functools.partial(_kernel, Hkv=Hkv, n=n, window=window,
-                             softcap=softcap)
+    kern = functools.partial(_kernel, Hkv=Hkv, n=n, ps=ps, ppb=ppb,
+                             window=window, softcap=softcap)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, n),
+            num_scalar_prefetch=3,
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, Hkv, TG, D),
-                             lambda b, j, tab: (b, 0, 0, 0)),
-                pl.BlockSpec((1, TG, 1), lambda b, j, tab: (b, 0, 0)),
-                pl.BlockSpec((1, ps, Hkv, D),
-                             lambda b, j, tab: (page(b, j, tab), 0, 0, 0)),
-                pl.BlockSpec((1, ps, Hkv, D),
-                             lambda b, j, tab: (page(b, j, tab), 0, 0, 0)),
-                pl.BlockSpec((1, 1, ps),
-                             lambda b, j, tab: (page(b, j, tab), 0, 0)),
+                pl.BlockSpec((1, Hkv, TG, D), lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, TG, 1), lambda b, *_: (b, 0, 0)),
+                hbm, hbm, hbm,
             ],
             out_specs=pl.BlockSpec((1, Hkv, TG, D),
-                                   lambda b, j, tab: (b, 0, 0, 0)),
+                                   lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
+                pltpu.VMEM((2, ppb * ps, Hkv, D), k_pool.dtype),
+                pltpu.VMEM((2, ppb * ps, Hkv, D), v_pool.dtype),
+                pltpu.VMEM((2, ppb * rpp, LANES), jnp.int32),
+                pltpu.SemaphoreType.DMA((3, 2)),
                 pltpu.VMEM((Hkv, TG, 1), jnp.float32),  # running max
                 pltpu.VMEM((Hkv, TG, 1), jnp.float32),  # running denom
                 pltpu.VMEM((Hkv, TG, D), jnp.float32),  # output accumulator
@@ -144,5 +279,6 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos, *,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, TG, D), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(page_rows, qh, qrow, k_pool, v_pool, kp3)
+    )(page_rows, first.astype(jnp.int32), end.astype(jnp.int32), qh, qrow,
+      k_pool, v_pool, _pos_rows(pos_pool))
     return out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)

@@ -470,16 +470,20 @@ def _page_coords(page_rows: jax.Array, logical: jax.Array, ps: int, P: int,
 def paged_attend(params, cfg: ModelConfig, q: jax.Array,
                  cache: PagedKVCache, page_rows: jax.Array,
                  qpos: jax.Array, *,
-                 window: Optional[int] = None) -> jax.Array:
+                 window: Optional[int] = None,
+                 active: Optional[jax.Array] = None) -> jax.Array:
     """Attend q (B,T,Hq,D) against the page pool through slot page tables
     page_rows (B,n) and project out.  Dispatches to the fused Pallas
     kernel (``kernels.paged_attn``) when ``use_paged_attn_kernel()`` says
     so — the TPU fast path, no gathered cache copy — and otherwise to the
     lax fallback (``gather_pages`` + ``attend_masked``).  Both paths see
-    the same position metadata, so masking semantics are identical."""
+    the same position metadata, so masking semantics are identical on
+    active rows.  ``active`` (B,) bool, when given, lets the kernel skip
+    inactive rows (their output is 0 there); the fallback ignores it, so
+    callers must not read an inactive row's output."""
     if use_paged_attn_kernel():
         o = paged_attention_fused(
-            q, cache.k, cache.v, cache.pos, page_rows, qpos,
+            q, cache.k, cache.v, cache.pos, page_rows, qpos, active,
             window=int(window) if window else 0,
             softcap=float(cfg.attn_softcap) if cfg.attn_softcap else 0.0)
     else:
@@ -530,7 +534,7 @@ def paged_decode_attention(params, cfg: ModelConfig, x: jax.Array,
         new_pos = cache.pos.at[phys, off].set(pos, mode="drop")
     new_cache = PagedKVCache(new_k, new_v, new_pos)
     out = paged_attend(params, cfg, q, new_cache, page_rows, pos[:, None],
-                       window=window)
+                       window=window, active=active)
     return out, new_cache
 
 
@@ -571,7 +575,7 @@ def paged_multitok_attention(params, cfg: ModelConfig, x: jax.Array,
         new_pos = cache.pos.at[phys, off].set(qpos, mode="drop")
     new_cache = PagedKVCache(new_k, new_v, new_pos)
     out = paged_attend(params, cfg, q, new_cache, page_rows, qpos,
-                       window=window)
+                       window=window, active=active)
     return out, new_cache
 
 

@@ -102,6 +102,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.configs.base import ATTN_GLOBAL
+from repro.kernels.paged_attn import page_extent, pages_per_block, walked_pages
 from repro.obs.registry import MetricRegistry
 from repro.obs.trace import Tracer
 from repro.serve.engine import InferenceEngine
@@ -1122,7 +1124,8 @@ class Scheduler:
         t0 = time.perf_counter()
         h = self.tracer.begin("prefill_chunk", at=t0, rid=r.rid,
                               cursor=adm.cursor, tokens=int(c),
-                              **self._page_work(1, [adm.cursor + c]))
+                              **self._page_work(
+                                  1, [(adm.cursor, adm.cursor + c - 1)]))
         self.state, tok = self.engine.insert_chunk(
             self.state, {"tokens": toks}, adm.slot, adm.cursor)
         first = int(np.asarray(tok)[0])     # sync point ends the timing
@@ -1153,18 +1156,28 @@ class Scheduler:
         self._note_first(r)
         return True
 
-    def _page_work(self, rows: int, keys) -> Dict[str, int]:
-        """Span arguments of one paged-attention call: ``live_pages``, the
-        pages that hold the keys each served slot attends to (``keys``:
-        its cached tokens once the call has written its first input), and
-        ``walked_pages``, the pages the kernel's grid walks, ``rows`` x
-        pages per slot whatever the slots hold.  Empty when not paged."""
+    def _page_work(self, rows: int, queries) -> Dict[str, int]:
+        """Span arguments of one paged-attention call over ``rows`` slot
+        rows, of a global-attention layer where the model has one:
+        ``live_pages``, the pages that hold the keys the served slots
+        attend to, and ``walked_pages``, the page slots the kernel walks
+        for them (each live extent rounded out to whole blocks; a row
+        that is not served walks none), from the helpers the kernel's
+        wrapper uses.  ``queries``: the first and last query position of
+        each served slot (under speculation, a verify's k+1 queries).
+        Empty when not paged."""
         if not self.engine.paged:
             return {}
-        ps = self.engine.page_size
+        cfg, ps, n = self.engine.cfg, self.engine.page_size, \
+            self.engine.pages_per_slot
+        window = cfg.window if ATTN_GLOBAL not in cfg.layer_kinds() else 0
+        q = np.asarray(queries, np.int64).reshape(-1, 2)
+        first, end = page_extent(q[:, 0], q[:, 1], ps=ps, n=n,
+                                 window=window)
+        walked = walked_pages(first, end, ppb=pages_per_block(ps, n), n=n)
         return {"rows": rows,
-                "live_pages": sum(-(-int(k) // ps) for k in keys),
-                "walked_pages": rows * self.engine.pages_per_slot}
+                "live_pages": int((end - first).sum()),
+                "walked_pages": int(walked.sum())}
 
     # -- speculation -------------------------------------------------------
     def _spec_round(self, active: Dict[int, Request], mask: np.ndarray):
@@ -1377,8 +1390,9 @@ class Scheduler:
                 h_dec = self.tracer.begin(
                     "decode_step", at=t0, slots=len(active),
                     **self._page_work(self.engine.slots, [
-                        len(r.prompt) + len(r.generated)
-                        for r in active.values()]))
+                        (p, p + self.spec_k) for p in (
+                            len(r.prompt) + len(r.generated) - 1
+                            for r in active.values())]))
                 if self.spec_k:
                     emitted, consumed = self._spec_round(active, mask)
                 else:

@@ -14,6 +14,9 @@ import pytest
 
 from repro.configs import get_config, smoke_variant
 from repro.kernels.common import use_interpret, use_paged_attn_kernel
+from repro.kernels.paged_attn import (
+    page_extent, pages_per_block, walked_pages,
+)
 from repro.kernels.paged_attn.ops import paged_attention_fused
 from repro.kernels.paged_attn.ref import paged_attention_ref
 from repro.models import transformer as T
@@ -27,7 +30,7 @@ PS = 4                                  # page size used throughout
 
 
 def _pool_and_slots(rng, lens, *, Hkv, D, n=4, extra_pages=2,
-                    recycled=(), dtype=jnp.float32):
+                    recycled=(), dtype=jnp.float32, ps=PS):
     """Build a pool + per-slot page tables for ``lens[b]`` cached tokens.
 
     Physical page ids are handed out in shuffled order (tables are NOT
@@ -35,46 +38,138 @@ def _pool_and_slots(rng, lens, *, Hkv, D, n=4, extra_pages=2,
     ``recycled`` lists (slot, logical_page) pairs whose pos entries are
     reset to -1 — a page reclaimed and reassigned mid-generation."""
     B = len(lens)
-    need = [-(-l // PS) for l in lens]
+    need = [-(-l // ps) for l in lens]
     P = sum(need) + extra_pages
     perm = rng.permutation(P)
-    k = jnp.asarray(rng.normal(0, 1, (P, PS, Hkv, D)), dtype)
-    v = jnp.asarray(rng.normal(0, 1, (P, PS, Hkv, D)), dtype)
-    pos = np.full((P, PS), -1, np.int32)
+    k = jnp.asarray(rng.normal(0, 1, (P, ps, Hkv, D)), dtype)
+    v = jnp.asarray(rng.normal(0, 1, (P, ps, Hkv, D)), dtype)
+    pos = np.full((P, ps), -1, np.int32)
     rows = np.full((B, n), -1, np.int32)
     it = iter(perm)
     for b, l in enumerate(lens):
         for j in range(need[b]):
             p = int(next(it))
             rows[b, j] = p
-            fill = min(PS, l - j * PS)
-            pos[p, :fill] = np.arange(j * PS, j * PS + fill)
+            fill = min(ps, l - j * ps)
+            pos[p, :fill] = np.arange(j * ps, j * ps + fill)
     for b, j in recycled:
         pos[rows[b, j]] = -1
     cache = PagedKVCache(k, v, jnp.asarray(pos))
     return cache, jnp.asarray(rows)
 
 
-@pytest.mark.parametrize("Hq,Hkv", [(4, 2), (2, 2), (4, 1)])
-@pytest.mark.parametrize("Tq", [1, 4, 7])
-@pytest.mark.parametrize("window,softcap", [(0, 0.0), (6, 0.0), (0, 30.0),
-                                            (6, 30.0)])
-def test_fused_matches_ref(rng, Hq, Hkv, Tq, window, softcap):
-    """Decode (Tq=1), verify (Tq=k+1) and prefill-chunk (Tq=chunk) shapes
-    vs the oracle, over ragged slot lengths and shuffled page tables."""
-    D, lens = 16, [9, 3, 14]
-    cache, rows = _pool_and_slots(rng, lens, Hkv=Hkv, D=D)
-    B = len(lens)
-    G = Hq // Hkv
-    qpos = jnp.asarray([[l - 1 + t for t in range(Tq)] for l in lens],
+# Page layouts.  "ragged": pages of 4 and 4 pages a slot, so a row is
+# one block.  "blocks": pages of 16, so a block is 8 pages (128 keys),
+# and 11 pages a slot, no multiple of 8: row 0's 150 keys end mid-block
+# in its second block, row 1's pages are all unassigned, row 2 is
+# inactive (an empty extent), row 3 fills its row to the short last
+# block, row 4 holds 6 keys; a window of 40 puts row 0's and row 3's
+# first live page inside their first block.  "odd-pages": pages of 6
+# (positions padded to 8 lanes), 21 pages a block.  "wide-pages": pages
+# of 160, one page a block, its positions two 128-lane rows.
+LAYOUTS = {
+    "ragged": dict(lens=[9, 3, 14], n=4, ps=PS, active=None,
+                   qpos0=[8, 2, 13]),
+    "blocks": dict(lens=[150, 0, 40, 176, 6], n=11, ps=16,
+                   active=[True, True, False, True, True],
+                   qpos0=[149, 20, 39, 168, 5]),
+    "odd-pages": dict(lens=[150, 40, 97], n=25, ps=6, active=None,
+                      qpos0=[140, 39, 96]),
+    "wide-pages": dict(lens=[400, 100, 161], n=3, ps=160,
+                       active=[True, False, True], qpos0=[395, 99, 160]),
+}
+
+
+def _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap, *, D=16,
+                 poison=False):
+    """Kernel vs oracle on ``LAYOUTS[layout]``: (got, want, active).
+    ``poison`` fills every pool page outside the rows' live extents with
+    NaN before the kernel runs; the oracle sees the clean pool."""
+    L = LAYOUTS[layout]
+    cache, rows = _pool_and_slots(rng, L["lens"], Hkv=Hkv, D=D, n=L["n"],
+                                  ps=L["ps"])
+    B, G = len(L["lens"]), Hq // Hkv
+    qpos = jnp.asarray([[p + t for t in range(Tq)] for p in L["qpos0"]],
                        jnp.int32)
+    active = None if L["active"] is None else jnp.asarray(L["active"])
     q = jnp.asarray(rng.normal(0, 1, (B, Tq, Hq, D)), jnp.float32)
-    got = paged_attention_fused(q, cache.k, cache.v, cache.pos, rows, qpos,
+    k, v = cache.k, cache.v
+    if poison:
+        first, end = page_extent(qpos.min(1), qpos.max(1), ps=L["ps"],
+                                 n=L["n"], window=window)
+        live = np.zeros(k.shape[0], bool)
+        for b in range(B):
+            if active is None or L["active"][b]:
+                ids = np.asarray(rows[b, int(first[b]):int(end[b])])
+                live[ids[ids >= 0]] = True
+        assert (~live).any()
+        k = jnp.where(jnp.asarray(live)[:, None, None, None], k, jnp.nan)
+        v = jnp.where(jnp.asarray(live)[:, None, None, None], v, jnp.nan)
+    got = paged_attention_fused(q, k, v, cache.pos, rows, qpos, active,
                                 window=window, softcap=softcap)
     want = paged_attention_ref(
         q.reshape(B, Tq, Hkv, G, D), cache.k, cache.v, cache.pos, rows,
         qpos, window=window, softcap=softcap).reshape(B, Tq, Hq, D)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    if active is not None:                  # an inactive row outputs 0
+        want = jnp.where(active[:, None, None, None], want, 0.0)
+    return np.asarray(got), np.asarray(want)
+
+
+_CASES = [pytest.param(Hq, Hkv, Tq, w, c, "ragged",
+                       id=f"{w}-{c}-{Tq}-{Hq}-{Hkv}")
+          for Hq, Hkv in [(4, 2), (2, 2), (4, 1)] for Tq in [1, 4, 7]
+          for w, c in [(0, 0.0), (6, 0.0), (0, 30.0), (6, 30.0)]] + [
+    pytest.param(4, 2, 1, 0, 0.0, "blocks", id="blocks-decode"),
+    pytest.param(4, 2, 7, 40, 0.0, "blocks", id="blocks-chunk-window"),
+    pytest.param(4, 1, 4, 40, 30.0, "blocks", id="blocks-verify-window-cap"),
+    pytest.param(2, 2, 1, 0, 30.0, "blocks", id="blocks-decode-cap"),
+    pytest.param(4, 2, 4, 40, 0.0, "odd-pages", id="odd-pages-window"),
+    pytest.param(4, 2, 4, 200, 30.0, "wide-pages", id="wide-pages-window"),
+]
+
+
+@pytest.mark.parametrize("Hq,Hkv,Tq,window,softcap,layout", _CASES)
+def test_fused_matches_ref(rng, Hq, Hkv, Tq, window, softcap, layout):
+    """Decode (Tq=1), verify (Tq=k+1) and prefill-chunk (Tq=chunk) shapes
+    vs the oracle, over ragged slot lengths and shuffled page tables, in
+    one-block rows and in rows the kernel walks block by block."""
+    got, want = _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_dead_pages_never_reach_the_math(rng):
+    """Every pool page outside the live extents holds NaN: unassigned,
+    past a row's last query, before its window, or an inactive row's.
+    The kernel's output stays finite and equal to the oracle's on the
+    clean pool, so no dead page is copied or scored."""
+    got, want = _blocks_case(rng, "blocks", 4, 2, 4, 40, 30.0, poison=True)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_walked_pages_rounds_extents_out_to_blocks():
+    """The count the scheduler reports and the kernel walks: a 20-page
+    extent spans three blocks, the last one short (21 pages a row, no
+    multiple of 8); an 8-page extent is one block; an empty extent walks
+    nothing; [5, 21) rounds out to [0, 21).  Never below the live pages,
+    never above rows x pages per slot; numpy and jax agree."""
+    n, ps = 21, 16
+    ppb = pages_per_block(ps, n)
+    assert ppb == 8
+    first, end = np.asarray([0, 0, 3, 5]), np.asarray([20, 8, 3, 21])
+    want = [21, 8, 0, 21]
+    got = walked_pages(first, end, ppb=ppb, n=n)
+    assert got.tolist() == want
+    live = int((end - first).sum())
+    assert live <= got.sum() <= len(first) * n
+    assert walked_pages(jnp.asarray(first), jnp.asarray(end), ppb=ppb,
+                        n=n).tolist() == want
+    # extents from query positions: the walk covers the last query's page
+    f, e = page_extent(np.asarray([0]), np.asarray([319]), ps=ps, n=n)
+    assert (f.tolist(), e.tolist()) == ([0], [20])
+    f, e = page_extent(np.asarray([300]), np.asarray([303]), ps=ps, n=n,
+                       window=100)
+    assert (f.tolist(), e.tolist()) == ([12], [19])
 
 
 def test_fused_matches_lax_gather_path(rng):
@@ -131,18 +226,22 @@ def _attn_setup(rng, key, arch="qwen2-1.5b", **over):
     return cfg, params, cache, rows
 
 
-@pytest.mark.parametrize("arch,over", [
-    ("qwen2-1.5b", {}),
-    ("gemma2-2b", {"window": 6}),       # windowed + softcapped GQA
+@pytest.mark.parametrize("arch,over,active", [
+    pytest.param("qwen2-1.5b", {}, None, id="qwen2-1.5b-over0"),
+    # windowed + softcapped GQA
+    pytest.param("gemma2-2b", {"window": 6}, None, id="gemma2-2b-over1"),
+    # row 1 inactive: the kernel walks none of its pages
+    pytest.param("qwen2-1.5b", {}, [True, False], id="qwen2-1.5b-active"),
 ])
 def test_dispatch_parity_multitok_and_decode(rng, key, monkeypatch,
-                                             arch, over):
+                                             arch, over, active):
     """paged_multitok_attention (the verify/prefill path) and
-    paged_decode_attention produce allclose outputs and IDENTICAL caches
-    under REPRO_PAGED_ATTN=1 vs =0."""
+    paged_decode_attention produce allclose outputs on active rows and
+    IDENTICAL caches under REPRO_PAGED_ATTN=1 vs =0."""
     cfg, params, cache, rows = _attn_setup(rng, key, arch, **over)
     B, Tq = rows.shape[0], 3
     window = cfg.window if arch == "gemma2-2b" else None
+    mask = None if active is None else jnp.asarray(active)
     x = jnp.asarray(rng.normal(0, 1, (B, Tq, cfg.d_model)), jnp.float32)
     xd = jnp.asarray(rng.normal(0, 1, (B, 1, cfg.d_model)), jnp.float32)
     pos0 = jnp.asarray([0, 2], jnp.int32)
@@ -150,16 +249,18 @@ def test_dispatch_parity_multitok_and_decode(rng, key, monkeypatch,
     for flag in ("0", "1"):
         monkeypatch.setenv("REPRO_PAGED_ATTN", flag)
         o_m, c = paged_multitok_attention(params, cfg, x, cache, rows, pos0,
-                                          window=window)
+                                          window=window, active=mask)
         o_d, c = paged_decode_attention(params, cfg, xd, c, rows, pos0 + Tq,
-                                        window=window)
+                                        window=window, active=mask)
         outs[flag] = (o_m, o_d)
         caches[flag] = c
     # post-projection outputs accumulate O(d_model) reassociation noise;
     # the bit-level claim is made on caches here and on greedy streams in
     # the e2e test below
+    live = slice(None) if active is None else np.asarray(active)
     for a, b in zip(outs["0"], outs["1"]):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+        np.testing.assert_allclose(np.asarray(a)[live], np.asarray(b)[live],
+                                   atol=5e-4)
     for a, b in zip(caches["0"], caches["1"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

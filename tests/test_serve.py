@@ -771,9 +771,11 @@ def test_paged_decode_names_each_layer_part(op_name_scopes):
 def test_paged_spans_count_live_and_walked_pages():
     """``prefill_chunk`` and ``decode_step`` spans carry the paged
     kernel's work: live pages (per served slot, its cached tokens over
-    the page size, rounded up) and walked pages (rows x pages per slot),
+    the page size, rounded up) and walked pages (each served slot's live
+    extent rounded out to whole blocks; a slot not served walks none),
     here against counts made by hand.  Slots 2, pages of 4, 3 pages a
-    slot, chunks of 4; prompts of 5 and 9 tokens, 3 tokens each."""
+    slot, so a block is the whole row's 3 pages; chunks of 4; prompts of
+    5 and 9 tokens, 3 tokens each."""
     from repro.obs import Tracer
     cfg = smoke_variant(get_config("qwen2-1.5b"))
     tracer = Tracer()
@@ -787,11 +789,11 @@ def test_paged_spans_count_live_and_walked_pages():
     assert got == [
         ("prefill_chunk", 1, 1, 3),     # A tokens 0-3: 4 keys
         ("prefill_chunk", 1, 2, 3),     # A token 4: 5 keys
-        ("decode_step", 2, 2, 6),       # A: 5 + 1 keys
+        ("decode_step", 2, 2, 3),       # A: 5 + 1 keys; B not served
         ("prefill_chunk", 1, 1, 3),     # B tokens 0-3
-        ("decode_step", 2, 2, 6),       # A: 7 keys; A is done
+        ("decode_step", 2, 2, 3),       # A: 7 keys; A is done
         ("prefill_chunk", 1, 2, 3),     # B tokens 4-7: 8 keys
         ("prefill_chunk", 1, 3, 3),     # B token 8: 9 keys
-        ("decode_step", 2, 3, 6),       # B: 10 keys
-        ("decode_step", 2, 3, 6),       # B: 11 keys; B is done
+        ("decode_step", 2, 3, 3),       # B: 10 keys
+        ("decode_step", 2, 3, 3),       # B: 11 keys; B is done
     ]

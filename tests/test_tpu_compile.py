@@ -30,9 +30,14 @@ WIDTHS = {
     "qwen2-1.5b": (2, 6, 128, 0, 0.0),
     "gemma2-2b": (4, 2, 256, 4096, 50.0),
 }
-# the three serve shapes: (slots B, query tokens T)
-SHAPES = {"decode": (SLOTS, 1), "verify": (SLOTS, SPEC_K + 1),
-          "chunk": (1, CHUNK)}
+N = -(-MAX_LEN // PS)                    # pages a slot: 36, no multiple of 8
+# the three serve shapes: (slots B, query tokens T, pages a slot n); the
+# decode and verify calls carry an ``active`` mask, a chunk does not
+SHAPES = {"decode": (SLOTS, 1, N), "verify": (SLOTS, SPEC_K + 1, N),
+          "chunk": (1, CHUNK, N),
+          # the long-document cell's 16 slots, with 531 pages a slot: the
+          # last of its 67 blocks of 8 pages is short
+          "decode-long": (16, 1, 531)}
 
 
 @pytest.fixture(scope="module")
@@ -60,22 +65,23 @@ def one_chip():
 @pytest.mark.parametrize("arch", list(WIDTHS))
 def test_paged_attention_compiles_natively(one_chip, arch, shape):
     Hkv, G, D, window, softcap = WIDTHS[arch]
-    B, T = SHAPES[shape]
-    n = -(-MAX_LEN // PS)
-    P = SLOTS * n
+    B, T, n = SHAPES[shape]
+    P = max(B, SLOTS) * n
 
     def spec(shp, dtype):
         return jax.ShapeDtypeStruct(shp, dtype, sharding=one_chip)
 
-    fn = jax.jit(lambda q, k, v, pos, rows, qpos: paged_attention_pallas(
-        q, k, v, pos, rows, qpos, window=window, softcap=softcap,
-        interpret=False))
+    fn = jax.jit(lambda q, k, v, pos, rows, qpos, active: (
+        paged_attention_pallas(q, k, v, pos, rows, qpos, active,
+                               window=window, softcap=softcap,
+                               interpret=False)))
     compiled = fn.lower(
         spec((B, T, Hkv, G, D), jnp.bfloat16),
         spec((P, PS, Hkv, D), jnp.bfloat16),
         spec((P, PS, Hkv, D), jnp.bfloat16),
         spec((P, PS), jnp.int32), spec((B, n), jnp.int32),
-        spec((B, T), jnp.int32)).compile()
+        spec((B, T), jnp.int32),
+        spec((B,), jnp.bool_) if B > 1 else None).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the kernel's instruction carries the pallas_call's name, which is
