@@ -38,6 +38,7 @@ class MoEConfig:
     d_ff_shared: int = 0              # hidden dim of shared expert(s); 0 -> d_ff_expert * num_shared
     aux_loss_coef: float = 0.01       # load-balance auxiliary loss
     capacity_factor: float = 1.25     # expert capacity slack (tokens dropped beyond)
+    norm_topk_prob: bool = True       # renormalise the top-k gate weights to sum to 1
     router_dtype: str = "float32"     # router math in fp32 for stability
 
     def __post_init__(self) -> None:

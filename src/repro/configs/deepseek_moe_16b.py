@@ -32,6 +32,7 @@ def config() -> ModelConfig:
             num_shared=2,
             d_ff_shared=2816,     # 2 shared experts x 1408
             aux_loss_coef=0.01,
+            norm_topk_prob=False,     # the published config's norm_topk_prob
         ),
         tp_mode="heads",
         source="arXiv:2401.06066",

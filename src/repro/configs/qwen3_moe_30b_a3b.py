@@ -31,6 +31,7 @@ def config() -> ModelConfig:
             d_ff_expert=768,
             num_shared=0,
             aux_loss_coef=0.001,
+            norm_topk_prob=True,
         ),
         tp_mode="heads",          # 32 heads / 16-way axis
         source="hf:Qwen/Qwen3-30B-A3B",

@@ -24,6 +24,10 @@ Contract:
   ``REPRO_PAGED_ATTN=0|lax`` forces the fallback; unset/``auto``: the
   kernel on TPU (where it is the fast path), the fallback elsewhere
   (interpret mode is a correctness tool, not a fast path).
+* ``use_moe_gmm_kernel()`` — whether the dropless expert layer's
+  grouped matmul (``kernels/moe_gmm``) is the megablox Pallas kernel
+  (on TPU) or ``jax.lax.ragged_dot`` (elsewhere).  Tests that need the
+  kernel off-TPU call ``moe_gmm.ops.grouped_matmul`` directly.
 
 Both are read at TRACE time: a jitted wrapper bakes the decision into its
 compiled executable, so flipping the environment variable affects new
@@ -51,4 +55,9 @@ def use_paged_attn_kernel() -> bool:
         return True
     if env in ("0", "lax", "off"):
         return False
+    return jax.default_backend() == "tpu"
+
+
+def use_moe_gmm_kernel() -> bool:
+    """Run the expert layer's grouped matmul as the Pallas kernel?  (lazy)"""
     return jax.default_backend() == "tpu"

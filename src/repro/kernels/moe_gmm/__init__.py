@@ -1,0 +1,1 @@
+from repro.kernels.moe_gmm.ops import moe_gmm  # noqa: F401

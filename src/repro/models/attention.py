@@ -326,7 +326,10 @@ def fill_cache_from_prefill(cache: KVCache, k: jax.Array, v: jax.Array) -> KVCac
     k_t = k[:, S - take:]
     v_t = v[:, S - take:]
     pos_t = jnp.arange(S - take, S, dtype=jnp.int32)
-    slots = pos_t % L                                           # (take,)
+    # without a wrap the ring's first S entries are written in order: a
+    # slice, not a scatter whose indices and updates are the same iota,
+    # which the TPU compiler's scatter fusion rejects (a check failure)
+    slots = slice(0, S) if S <= L else pos_t % L
     new_k = cache.k.at[:, slots].set(k_t.astype(cache.k.dtype))
     new_v = cache.v.at[:, slots].set(v_t.astype(cache.v.dtype))
     new_pos = cache.pos.at[:, slots].set(pos_t[None, :].repeat(B, 0))

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN with capacity-based gather/scatter dispatch.
+"""Mixture-of-Experts FFN: capacity-based gather/scatter dispatch for
+training, and a dropless grouped-matmul layer for serving.
 
 Expert-parallel design (DESIGN.md §4/§5): the expert dim of the stacked
 expert weights is sharded over the "model" mesh axis (the paper's
@@ -11,6 +12,10 @@ expert-parallel all-reduce over "model".
 Unlike one-hot einsum dispatch (O(tokens^2) FLOPs), gather/scatter dispatch
 keeps compiled FLOPs at the *active* compute: tokens x top_k x d x ff.
 Dropped-token handling follows the standard capacity-factor scheme.
+
+Serving (``moe_dropless``) drops nothing: it sorts the (token, expert)
+pairs by expert and runs the experts that received pairs as grouped
+matmuls (``kernels/moe_gmm``) over exactly those pairs.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.distributed.sharding import ParamFactory, constrain
+from repro.kernels.moe_gmm import moe_gmm
 from repro.models.mlp import _act, mlp_block, mlp_params
 
 
@@ -52,8 +58,9 @@ def route(router_w: jax.Array, x: jax.Array, m: MoEConfig):
                         router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     topk_w, topk_idx = jax.lax.top_k(probs, m.top_k)
-    # deepseek-style: renormalize the selected weights
-    topk_w = topk_w / jnp.maximum(jnp.sum(topk_w, axis=-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        topk_w = topk_w / jnp.maximum(jnp.sum(topk_w, axis=-1, keepdims=True),
+                                      1e-9)
     return probs, topk_idx, topk_w
 
 
@@ -232,3 +239,50 @@ def moe_block(params, cfg: ModelConfig, x: jax.Array):
     if m.num_shared:
         y = y + mlp_block(params["shared"], cfg.act, x)
     return y, aux
+
+
+def moe_dropless(params, cfg: ModelConfig, x: jax.Array,
+                 active: Optional[jax.Array] = None):
+    """Dropless expert layer: x (B,S,d) -> (y (B,S,d), counts (E,) int32,
+    the (token, expert) pairs each expert received).
+
+    Routes in float32 over every expert and keeps each token's top-k;
+    sorts the B*S*k pairs by expert; runs gate, up and down as grouped
+    matmuls over the sorted rows, so an expert computes exactly its
+    pairs and one that received none is not read; then weights each
+    pair's output by its gate and sums a token's k outputs.  No capacity,
+    no aux loss.  ``active`` (B,) bool leaves a row's pairs out of every
+    expert and of the counts; its output is then the shared expert's
+    alone (0 without one).  Inside a layer scan the expert weights come
+    stacked over the scanned layers with ``params["layer"]`` this layer's
+    index, and are read in place (``kernels/moe_gmm``)."""
+    m = cfg.moe
+    B, S, d = x.shape
+    T, K = B * S, m.top_k
+    with jax.named_scope("moe"):
+        with jax.named_scope("route"):
+            _, topk_idx, topk_w = route(params["router"], x, m)
+            flat_e = topk_idx.reshape(T * K)
+            if active is not None:      # past the last expert: sorts last
+                flat_e = jnp.where(jnp.repeat(active, S * K), flat_e,
+                                   m.num_experts)
+            order = jnp.argsort(flat_e, stable=True)
+            counts = jnp.bincount(flat_e, length=m.num_experts
+                                  ).astype(jnp.int32)
+            rows = x.reshape(T, d)[order // K]                  # (T*K, d)
+        with jax.named_scope("experts"):
+            layer = params.get("layer")
+            g = moe_gmm(rows, params["w_gate"], counts, layer)
+            u = moe_gmm(rows, params["w_up"], counts, layer)
+            h = _act(g, cfg.act) * u
+            out = moe_gmm(h, params["w_down"], counts, layer)   # (T*K, d) f32
+            if active is not None:      # rows past the counts: undefined
+                out = jnp.where((jnp.arange(T * K) < counts.sum())[:, None],
+                                out, 0.0)
+        with jax.named_scope("combine"):
+            per_pair = out[jnp.argsort(order)].reshape(T, K, d)
+            y = jnp.einsum("tkd,tk->td", per_pair, topk_w.reshape(T, K))
+            y = y.astype(x.dtype).reshape(B, S, d)
+        if m.num_shared:
+            y = y + mlp_block(params["shared"], cfg.act, x)
+    return y, counts

@@ -203,24 +203,33 @@ def _mixer_scope(kind: LayerKind) -> str:
     return "attn" if kind in (ATTN_GLOBAL, ATTN_LOCAL) else kind
 
 
-def _apply_ffn(lp, cfg: ModelConfig, x: jax.Array, ffn: str):
+def _apply_ffn(lp, cfg: ModelConfig, x: jax.Array, ffn: str, *,
+               dropless: bool = False, active=None):
+    """Returns (x, aux, counts): the capacity path's load-balance loss,
+    and with ``dropless`` (the serving paths) the expert layer's per-expert
+    pair counts (E,) int32 over the ``active`` rows (default all); counts
+    is None for a dense or absent FFN."""
+    zero = jnp.zeros((), jnp.float32)
     if ffn == "none":
-        return x, jnp.zeros((), jnp.float32)
+        return x, zero, None
     with jax.named_scope("mlp"):
         h = apply_norm(lp["norm2"], cfg.norm, x)
+        counts = None
+        aux = zero
         if ffn == "dense":
             out = mlp_mod.mlp_block(lp["ffn"], cfg, h)
-            aux = jnp.zeros((), jnp.float32)
+        elif dropless:
+            out, counts = moe_mod.moe_dropless(lp["ffn"], cfg, h, active)
         else:
             out, aux = moe_mod.moe_block_auto(lp["ffn"], cfg, h)
         if cfg.post_norms:
             out = apply_norm(lp["post_norm2"], cfg.norm, out)
-        return x + out, aux
+        return x + out, aux, counts
 
 
 def apply_layer(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
                 ffn: str, *, collect_cache: bool = False,
-                max_len: int = 0):
+                max_len: int = 0, dropless: bool = False):
     """Full-sequence layer.  Returns (x, aux, cache_entry_or_None)."""
     with jax.named_scope(_mixer_scope(kind)):
         h = apply_norm(lp["norm1"], cfg.norm, x)
@@ -255,29 +264,31 @@ def apply_layer(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
         if cfg.post_norms:
             out = apply_norm(lp["post_norm1"], cfg.norm, out)
     x = x + out
-    x, aux = _apply_ffn(lp, cfg, x, ffn)
+    x, aux, _ = _apply_ffn(lp, cfg, x, ffn, dropless=dropless)
     return x, aux, cache_entry
 
 
 def _apply_layer_step(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
-                      ffn: str, mixer_fn):
+                      ffn: str, mixer_fn, active=None):
     """Shared incremental-layer scaffold (norm -> mixer -> post-norm ->
     residual -> FFN) for the one-token and chunked paths; ``mixer_fn(lp,
     kind, h) -> (out, new_cache_entry)`` supplies the cached
-    attention/recurrent step."""
+    attention/recurrent step.  The FFN is the dropless expert layer where
+    the layer has experts; ``active`` (B,) rows leave the experts out.
+    Returns (x, new_entry, expert counts or None)."""
     with jax.named_scope(_mixer_scope(kind)):
         h = apply_norm(lp["norm1"], cfg.norm, x)
         out, new_entry = mixer_fn(lp, kind, h)
         if cfg.post_norms:
             out = apply_norm(lp["post_norm1"], cfg.norm, out)
     x = x + out
-    x, _ = _apply_ffn(lp, cfg, x, ffn)
-    return x, new_entry
+    x, _, counts = _apply_ffn(lp, cfg, x, ffn, dropless=True, active=active)
+    return x, new_entry, counts
 
 
 def apply_layer_decode(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
                        ffn: str, cache_entry, position: jax.Array):
-    """One-token layer step.  Returns (x, new_cache_entry)."""
+    """One-token layer step.  Returns (x, new_cache_entry, counts)."""
     def mixer(lp_, kind_, h):
         if kind_ in (ATTN_GLOBAL, ATTN_LOCAL):
             window = cfg.window if kind_ == ATTN_LOCAL else None
@@ -295,10 +306,41 @@ def apply_layer_decode(lp, cfg: ModelConfig, x: jax.Array, kind: LayerKind,
 # ---------------------------------------------------------------------------
 # Full stack
 # ---------------------------------------------------------------------------
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _hold_experts(blocks):
+    """Split the stacked block params into what a layer scan slices and
+    the stacked expert weights it closes over instead.  A Pallas call
+    takes a sliced operand as a copy (one layer's experts are 1.2 GB at
+    Qwen3-30B-A3B's widths, copied on every step), so the dropless layer
+    reads its layer's experts in place in the whole stack, by index."""
+    sliced, held = {}, {}
+    for k, lp in blocks.items():
+        ffn = lp.get("ffn", {})
+        if "router" in ffn:
+            sliced[k] = dict(lp, ffn={n: v for n, v in ffn.items()
+                                      if n not in _EXPERT_WEIGHTS})
+            held[k] = {n: ffn[n] for n in _EXPERT_WEIGHTS}
+        else:
+            sliced[k] = lp
+    return sliced, held
+
+
+def _with_held(block_p, held, i: int, rep):
+    """Pattern entry ``i`` of a scanned block, its held expert weights put
+    back whole with the block's index ``rep`` as their ``layer``."""
+    lp = block_p[str(i)]
+    if str(i) not in held:
+        return lp
+    return dict(lp, ffn=dict(lp["ffn"], **held[str(i)], layer=rep))
+
+
 def run_stack(params, cfg: ModelConfig, x: jax.Array, *,
               collect_cache: bool = False, max_len: int = 0,
-              remat: bool = False):
-    """x (B,S,d) -> (x, aux, caches) through prefix + scanned blocks + suffix."""
+              remat: bool = False, dropless: bool = False):
+    """x (B,S,d) -> (x, aux, caches) through prefix + scanned blocks + suffix.
+    ``dropless`` runs expert layers through the serving layer."""
     if cfg.contribution_gate:
         x = contribution_gate(params["gate"], x)
     prefix, pat, n_rep, suffix = stack_plan(cfg)
@@ -310,21 +352,27 @@ def run_stack(params, cfg: ModelConfig, x: jax.Array, *,
         for lp, kind in zip(params["prefix"], prefix):
             x, a, c = apply_layer(lp, cfg, x, kind,
                                   _ffn_kind(cfg, kind, in_prefix=True),
-                                  collect_cache=collect_cache, max_len=max_len)
+                                  collect_cache=collect_cache, max_len=max_len,
+                                  dropless=dropless)
             aux = aux + a
             entries.append(c)
         if collect_cache:
             caches["prefix"] = tuple(entries)
 
     if n_rep:
-        def body(carry, block_p):
+        sliced, held = _hold_experts(params["blocks"]) if dropless \
+            else (params["blocks"], {})
+
+        def body(carry, inp):
             xx, au = carry
+            block_p, rep = inp
             entries = []
             for i, kind in enumerate(pat):
                 xx, a, c = apply_layer(
-                    block_p[str(i)], cfg, xx, kind,
+                    _with_held(block_p, held, i, rep), cfg, xx, kind,
                     _ffn_kind(cfg, kind, in_prefix=False),
-                    collect_cache=collect_cache, max_len=max_len)
+                    collect_cache=collect_cache, max_len=max_len,
+                    dropless=dropless)
                 au = au + a
                 entries.append(c)
             ys = {str(i): e for i, e in enumerate(entries)} \
@@ -332,8 +380,9 @@ def run_stack(params, cfg: ModelConfig, x: jax.Array, *,
             return (xx, au), ys
         if remat:
             body = jax.checkpoint(body)
-        (x, aux), block_caches = jax.lax.scan(body, (x, aux), params["blocks"],
-                                              unroll=_scan_unroll())
+        (x, aux), block_caches = jax.lax.scan(
+            body, (x, aux), (sliced, jnp.arange(n_rep)),
+            unroll=_scan_unroll())
         if collect_cache:
             caches["blocks"] = block_caches
 
@@ -342,7 +391,8 @@ def run_stack(params, cfg: ModelConfig, x: jax.Array, *,
         for lp, kind in zip(params["suffix"], suffix):
             x, a, c = apply_layer(lp, cfg, x, kind,
                                   _ffn_kind(cfg, kind, in_prefix=False),
-                                  collect_cache=collect_cache, max_len=max_len)
+                                  collect_cache=collect_cache, max_len=max_len,
+                                  dropless=dropless)
             aux = aux + a
             entries.append(c)
         if collect_cache:
@@ -426,7 +476,7 @@ def prefill(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     """Full-context forward; returns (last-token logits (B,V), caches)."""
     x = embed_inputs(params, cfg, inputs, dtype)
     x, _, caches = run_stack(params, cfg, x, collect_cache=True,
-                             max_len=max_len)
+                             max_len=max_len, dropless=True)
     last = x[:, -1:]
     logits = unembed(params["embed"], last, tie=cfg.tie_embeddings,
                      cap=cfg.logit_softcap, real_vocab=cfg.vocab_size)[:, 0]
@@ -436,42 +486,53 @@ def prefill(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
 def _decode_walk(params, cfg: ModelConfig, x: jax.Array, caches, layer_fn):
     """Shared prefix / scanned-blocks / suffix traversal for the one-token
     and chunked incremental paths.  ``layer_fn(lp, kind, ffn, cache_entry,
-    x) -> (x, new_entry)`` supplies the per-layer step (contiguous decode,
-    paged decode, or paged chunk prefill)."""
+    x) -> (x, new_entry, counts)`` supplies the per-layer step (contiguous
+    decode, paged decode, or paged chunk prefill).  Returns (x, caches,
+    counts): the expert layers' per-expert pair counts (expert layers, E)
+    int32 in layer order, None for a model without experts."""
     prefix, pat, n_rep, suffix = stack_plan(cfg)
     new_caches: Dict[str, Any] = {}
+    counts = []
 
     if prefix:
         entries = []
         for lp, kind, ce in zip(params["prefix"], prefix, caches["prefix"]):
-            x, ne = layer_fn(lp, kind, _ffn_kind(cfg, kind, in_prefix=True),
-                             ce, x)
+            x, ne, c = layer_fn(lp, kind,
+                                _ffn_kind(cfg, kind, in_prefix=True), ce, x)
             entries.append(ne)
+            counts += [c[None]] if c is not None else []
         new_caches["prefix"] = tuple(entries)
 
     if n_rep:
+        sliced, held = _hold_experts(params["blocks"])
+
         def body(xx, inp):
-            block_p, block_c = inp
-            entries = []
+            block_p, block_c, rep = inp
+            entries, cs = [], []
             for i, kind in enumerate(pat):
-                xx, ne = layer_fn(block_p[str(i)], kind,
-                                  _ffn_kind(cfg, kind, in_prefix=False),
-                                  block_c[str(i)], xx)
+                xx, ne, c = layer_fn(_with_held(block_p, held, i, rep), kind,
+                                     _ffn_kind(cfg, kind, in_prefix=False),
+                                     block_c[str(i)], xx)
                 entries.append(ne)
-            return xx, {str(i): e for i, e in enumerate(entries)}
-        x, block_caches = jax.lax.scan(
-            body, x, (params["blocks"], caches["blocks"]),
+                cs += [c] if c is not None else []
+            return xx, ({str(i): e for i, e in enumerate(entries)},
+                        jnp.stack(cs) if cs else None)
+        x, (block_caches, block_counts) = jax.lax.scan(
+            body, x, (sliced, caches["blocks"], jnp.arange(n_rep)),
             unroll=_scan_unroll())
         new_caches["blocks"] = block_caches
+        if block_counts is not None:        # (n_rep, per pattern, E)
+            counts.append(block_counts.reshape(-1, block_counts.shape[-1]))
 
     if suffix:
         entries = []
         for lp, kind, ce in zip(params["suffix"], suffix, caches["suffix"]):
-            x, ne = layer_fn(lp, kind, _ffn_kind(cfg, kind, in_prefix=False),
-                             ce, x)
+            x, ne, c = layer_fn(lp, kind,
+                                _ffn_kind(cfg, kind, in_prefix=False), ce, x)
             entries.append(ne)
+            counts += [c[None]] if c is not None else []
         new_caches["suffix"] = tuple(entries)
-    return x, new_caches
+    return x, new_caches, (jnp.concatenate(counts) if counts else None)
 
 
 def _finish_logits(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -497,7 +558,7 @@ def decode_step(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     def layer_fn(lp, kind, ffn, ce, xx):
         return apply_layer_decode(lp, cfg, xx, kind, ffn, ce, position)
 
-    x, new_caches = _decode_walk(params, cfg, x, caches, layer_fn)
+    x, new_caches, _ = _decode_walk(params, cfg, x, caches, layer_fn)
     logits = _finish_logits(params, cfg, x)[:, 0]
     return logits, new_caches
 
@@ -525,7 +586,9 @@ def decode_step_paged(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     """Fused all-slot decode against the paged cache.  ``page_table``
     (S, pages_per_slot) int32 physical page ids per slot (-1 unassigned);
     ``active`` (S,) bool gates every state write — inactive slots neither
-    write KV pages nor advance recurrent state."""
+    write KV pages nor advance recurrent state.  Returns (logits (S,V),
+    caches, expert counts (expert layers, E) int32 or None); the counts
+    take every row, active or not."""
     x = embed_inputs(params, cfg, inputs, dtype)
     if cfg.contribution_gate:
         x = contribution_gate(params["gate"], x)
@@ -544,11 +607,12 @@ def decode_step_paged(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
             else:
                 raise ValueError(kind_)
             return out, _mask_state_update(ne, ce, active)
-        return _apply_layer_step(lp, cfg, xx, kind, ffn, mixer)
+        return _apply_layer_step(lp, cfg, xx, kind, ffn, mixer,
+                                 active=active)
 
-    x, new_caches = _decode_walk(params, cfg, x, caches, layer_fn)
+    x, new_caches, counts = _decode_walk(params, cfg, x, caches, layer_fn)
     logits = _finish_logits(params, cfg, x)[:, 0]
-    return logits, new_caches
+    return logits, new_caches, counts
 
 
 def _verify_recurrent(step_fn, lp, cfg: ModelConfig, x: jax.Array, entry):
@@ -588,7 +652,7 @@ def verify_step_paged(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
 
     ``active`` (S,) bool gates the page writes; inactive slots' recurrent
     rows are restored at selection time.  Returns (logits (S, T, V),
-    caches-with-stacked-recurrent-leaves)."""
+    caches-with-stacked-recurrent-leaves, expert counts or None)."""
     x = embed_inputs(params, cfg, inputs, dtype)
     if cfg.contribution_gate:
         x = contribution_gate(params["gate"], x)
@@ -607,11 +671,12 @@ def verify_step_paged(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
                 return _verify_recurrent(ssm_mod.ssm_decode_step,
                                          lp_["ssm"], cfg, h, ce)
             raise ValueError(kind_)
-        return _apply_layer_step(lp, cfg, xx, kind, ffn, mixer)
+        return _apply_layer_step(lp, cfg, xx, kind, ffn, mixer,
+                                 active=active)
 
-    x, new_caches = _decode_walk(params, cfg, x, caches, layer_fn)
+    x, new_caches, counts = _decode_walk(params, cfg, x, caches, layer_fn)
     logits = _finish_logits(params, cfg, x)                     # (S, T, V)
-    return logits, new_caches
+    return logits, new_caches, counts
 
 
 def _chunk_recurrent(step_fn, lp, cfg: ModelConfig, x: jax.Array, entry,
@@ -644,7 +709,8 @@ def prefill_chunk(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     (1, C) is the chunk starting at absolute position ``pos_start``; KV is
     written into the slot's pages and recurrent state advances in the
     slot's row, so admission interleaves with fused decode steps without
-    touching any other slot.  Returns (last-token logits (1, V), caches).
+    touching any other slot.  Returns (last-token logits (1, V), caches,
+    expert counts (expert layers, E) int32 or None).
     """
     x = embed_inputs(params, cfg, inputs, dtype)
     if cfg.contribution_gate:
@@ -668,9 +734,9 @@ def prefill_chunk(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
             raise ValueError(kind_)
         return _apply_layer_step(lp, cfg, xx, kind, ffn, mixer)
 
-    x, new_caches = _decode_walk(params, cfg, x, caches, layer_fn)
+    x, new_caches, counts = _decode_walk(params, cfg, x, caches, layer_fn)
     logits = _finish_logits(params, cfg, x)[:, -1]
-    return logits, new_caches
+    return logits, new_caches, counts
 
 
 def scatter_prefill_paged(cfg: ModelConfig, paged_caches, prefill_caches,

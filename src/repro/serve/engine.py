@@ -104,6 +104,12 @@ class InferenceEngine:
         self._jit_cache: dict = {}
         self._state_shardings = None
         self._has_rec: Optional[bool] = None
+        #: the last step's per-expert pair counts, (expert layers, E) int32
+        #: on the device, for a model with experts: what each decode,
+        #: verify and prefill-chunk step returns beside its tokens, for the
+        #: caller to fetch with them.  None after any other step, and
+        #: always None without experts.
+        self.last_expert_counts = None
 
     @property
     def mesh(self):
@@ -473,7 +479,7 @@ class InferenceEngine:
 
     def _chunk_fn(self, state: InferenceState, inputs: Dict[str, jax.Array],
                   slot: jax.Array, pos_start: jax.Array):
-        logits, cache = tfm.prefill_chunk(
+        logits, cache, counts = tfm.prefill_chunk(
             state.params, self.cfg, inputs, state.cache,
             state.page_table[slot], slot, pos_start, dtype=self.dtype)
         end = pos_start + inputs["tokens"].shape[1]
@@ -485,7 +491,7 @@ class InferenceEngine:
             cache=cache,
             positions=state.positions.at[slot].set(end),
             last_tok=state.last_tok.at[slot].set(tok[0]),
-        ), tok
+        ), tok, counts
 
     def _decode_fn(self, state: InferenceState):
         logits, cache = tfm.decode_step(
@@ -497,7 +503,7 @@ class InferenceEngine:
                               last_tok=tok, tok_presence=presence), tok
 
     def _decode_paged_fn(self, state: InferenceState, active: jax.Array):
-        logits, cache = tfm.decode_step_paged(
+        logits, cache, counts = tfm.decode_step_paged(
             state.params, self.cfg, {"tokens": state.last_tok[:, None]},
             state.cache, state.positions, state.page_table, active,
             dtype=self.dtype)
@@ -508,7 +514,7 @@ class InferenceEngine:
             positions=state.positions + active.astype(jnp.int32),
             last_tok=jnp.where(active, tok, state.last_tok),
             tok_presence=presence,
-        ), tok
+        ), tok, counts
 
     def _verify_fn(self, state: InferenceState, drafts: jax.Array,
                    draft_len: jax.Array, active: jax.Array):
@@ -533,7 +539,7 @@ class InferenceEngine:
         token."""
         S, K = drafts.shape
         toks = jnp.concatenate([state.last_tok[:, None], drafts], axis=1)
-        logits, stacked = tfm.verify_step_paged(
+        logits, stacked, counts = tfm.verify_step_paged(
             state.params, self.cfg, {"tokens": toks}, state.cache,
             state.positions, state.page_table, active, dtype=self.dtype)
         greedy = jnp.argmax(logits, -1).astype(jnp.int32)       # (S, K+1)
@@ -584,7 +590,7 @@ class InferenceEngine:
             positions=state.positions + consumed,
             last_tok=jnp.where(active, last, state.last_tok),
             tok_presence=presence,
-        ), target, consumed
+        ), target, consumed, counts
 
     def _active_sharding(self):
         return NamedSharding(self.mesh, resolve_pspec(
@@ -618,8 +624,8 @@ class InferenceEngine:
                              self._active_sharding())
                 else:
                     in_sh = (st_sh, self._active_sharding())
-                out_sh = (st_sh, None, None) if kind == "verify" \
-                    else (st_sh, None)
+                out_sh = (st_sh,) + (None,) * {
+                    "verify": 3, "chunk": 2, "decode_paged": 2}.get(kind, 1)
                 jfn = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                               donate_argnums=donate)
             self._jit_cache[key] = jfn
@@ -642,6 +648,7 @@ class InferenceEngine:
         # annotate() scopes are live only inside an open jax.profiler
         # window (--profile-dir): host phase names then line up with the
         # device timeline; otherwise they are null contexts
+        self.last_expert_counts = None
         with profiler.annotate("serve.insert"):
             return self._run(jfn, state, inputs,
                              jnp.asarray(slot, jnp.int32))
@@ -654,14 +661,15 @@ class InferenceEngine:
         (state, greedy token (1,)) — the token is meaningful only for the
         final chunk of a prompt.  Jit-cached per chunk shape, so a prompt
         split into fixed-size chunks compiles twice at most (body +
-        remainder)."""
+        remainder).  Sets ``last_expert_counts``."""
         assert self.paged, "insert_chunk requires the paged cache"
         inputs = {k: jnp.asarray(v) for k, v in inputs.items()}
         jfn = self._get_jit("chunk", state, inputs)
         with profiler.annotate("serve.insert_chunk"):
-            return self._run(jfn, state, inputs,
-                             jnp.asarray(slot, jnp.int32),
-                             jnp.asarray(pos_start, jnp.int32))
+            state, tok, self.last_expert_counts = self._run(
+                jfn, state, inputs, jnp.asarray(slot, jnp.int32),
+                jnp.asarray(pos_start, jnp.int32))
+        return state, tok
 
     def verify(self, state: InferenceState, drafts, draft_len, active):
         """One fused speculative decode step over ALL slots.  ``drafts``
@@ -673,7 +681,8 @@ class InferenceEngine:
         ``emitted[s, :consumed[s]]`` — its own continuation under its
         sampling params (argmax for greedy slots), bit-identical to
         ``consumed[s]`` successive :meth:`decode` calls — and advanced
-        its position by ``consumed[s]``.  Jit-cached per K."""
+        its position by ``consumed[s]``.  Jit-cached per K.  Sets
+        ``last_expert_counts``."""
         if not self.paged:
             raise ValueError("speculative verification writes draft KV "
                              "through page tables; build the engine with "
@@ -682,9 +691,10 @@ class InferenceEngine:
         drafts = jnp.asarray(drafts, jnp.int32)
         jfn = self._get_jit("verify", state, {"drafts": drafts})
         with profiler.annotate("serve.verify"):
-            return self._run(jfn, state, drafts,
-                             jnp.asarray(draft_len, jnp.int32),
-                             jnp.asarray(active, bool))
+            state, emitted, consumed, self.last_expert_counts = self._run(
+                jfn, state, drafts, jnp.asarray(draft_len, jnp.int32),
+                jnp.asarray(active, bool))
+        return state, emitted, consumed
 
     def lower_decode(self, state: InferenceState) -> jax.stages.Lowered:
         """Lower the fused all-slot decode step for ``state`` (arrays or
@@ -708,16 +718,20 @@ class InferenceEngine:
         clears their page rows, turning stray writes into dropped
         scatters), but the mask is REQUIRED while any slot is
         mid-chunked-prefill — only the caller knows those slots, and an
-        unmasked decode would advance their recurrent state."""
+        unmasked decode would advance their recurrent state.  Sets
+        ``last_expert_counts`` (the active rows' pairs)."""
         if self.paged:
             if active is None:
                 active = np.ones((self.slots,), bool)
             jfn = self._get_jit("decode_paged", state)
             with profiler.annotate("serve.decode"):
-                return self._run(jfn, state, jnp.asarray(active, bool))
+                state, tok, self.last_expert_counts = self._run(
+                    jfn, state, jnp.asarray(active, bool))
+            return state, tok
         if active is not None:
             raise ValueError("active masks are a paged-mode feature; the "
                              "contiguous decode advances every slot")
         jfn = self._get_jit("decode", state)
+        self.last_expert_counts = None
         with profiler.annotate("serve.decode"):
             return self._run(jfn, state)

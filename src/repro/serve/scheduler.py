@@ -100,6 +100,7 @@ from collections import Counter, OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ATTN_GLOBAL
@@ -711,6 +712,14 @@ _STAT_DEFAULTS: Dict[str, float] = {
     # requests restored into a slot
     "preemptions": 0, "restores": 0}
 
+#: expert-layer counters, present only for a model with experts: over the
+#: run's decode (and verify) steps and prefill chunks, the (token, expert)
+#: pairs summed over layers, the experts that received a pair summed over
+#: layers, and the most pairs one expert of one layer took in a step
+_MOE_STAT_DEFAULTS: Dict[str, float] = {
+    "expert_pairs": 0, "experts_touched": 0, "expert_max_load": 0}
+_MAXIMA = ("max_decode_gap_s", "max_defer_cycles", "expert_max_load")
+
 
 class Scheduler:
     """Drives an :class:`InferenceEngine` over a queue of requests."""
@@ -761,6 +770,9 @@ class Scheduler:
         #: accumulated across every finished/aborted run() on this scheduler
         self.lifetime_stats = self.registry.group("sched.lifetime",
                                                   _STAT_DEFAULTS)
+        if engine.cfg.moe is not None:
+            self.stats.merge_defaults(_MOE_STAT_DEFAULTS)
+            self.lifetime_stats.merge_defaults(_MOE_STAT_DEFAULTS)
         #: decode-gap DISTRIBUTION (the stall metric): per run, like
         #: ``stats`` — ``decode_gaps.quantile(99)`` replaces eyeballing
         #: the ``max_decode_gap_s`` scalar (which stays, as the p100)
@@ -815,7 +827,7 @@ class Scheduler:
 
     def _fold_lifetime(self) -> None:
         for k, v in self.stats.items():
-            if k in ("max_decode_gap_s", "max_defer_cycles"):  # maxima
+            if k in _MAXIMA:
                 self.lifetime_stats[k] = max(self.lifetime_stats[k], v)
             else:
                 self.lifetime_stats[k] += v
@@ -1128,9 +1140,10 @@ class Scheduler:
                                   1, [(adm.cursor, adm.cursor + c - 1)]))
         self.state, tok = self.engine.insert_chunk(
             self.state, {"tokens": toks}, adm.slot, adm.cursor)
-        first = int(np.asarray(tok)[0])     # sync point ends the timing
+        tok, moe = self._fetch(tok)         # sync point ends the timing
+        first = int(tok[0])
         now = time.perf_counter()
-        self.tracer.end(h, at=now)
+        self.tracer.end(h, at=now, **moe)
         self.stats["prefill_s"] += now - t0
         self.stats["prefill_tokens"] += c
         self.stats["prefill_chunks"] += 1
@@ -1155,6 +1168,25 @@ class Scheduler:
         self.admission_order.append(r.rid)
         self._note_first(r)
         return True
+
+    def _fetch(self, *outs):
+        """The step's outputs on the host, with the engine's expert counts
+        in the same transfer where the model has experts.  Returns (*outs,
+        span args): ``expert_pairs``, ``experts_touched`` and
+        ``expert_max_load`` of the step (empty without experts), which are
+        also added to the run's counters."""
+        counts = self.engine.last_expert_counts
+        if counts is None:
+            return (*(np.asarray(o) for o in outs), {})
+        *outs, counts = jax.device_get((*outs, counts))
+        moe = {"expert_pairs": int(counts.sum()),
+               "experts_touched": int((counts > 0).sum()),
+               "expert_max_load": int(counts.max())}
+        self.stats["expert_pairs"] += moe["expert_pairs"]
+        self.stats["experts_touched"] += moe["experts_touched"]
+        self.stats["expert_max_load"] = max(self.stats["expert_max_load"],
+                                            moe["expert_max_load"])
+        return (*outs, moe)
 
     def _page_work(self, rows: int, queries) -> Dict[str, int]:
         """Span arguments of one paged-attention call over ``rows`` slot
@@ -1183,10 +1215,10 @@ class Scheduler:
     def _spec_round(self, active: Dict[int, Request], mask: np.ndarray):
         """One speculative decode round: draft for every active slot with
         budget headroom, verify all drafts in one fused forward.  Returns
-        (emitted (slots, >=1) greedy tokens, consumed (slots,)); falls
-        back to the plain fused decode when nothing was drafted (so an
-        empty-handed drafter costs a (slots, K+1)-shaped forward nothing).
-        """
+        (emitted (slots, >=1) greedy tokens, consumed (slots,), expert span
+        args); falls back to the plain fused decode when nothing was
+        drafted (so an empty-handed drafter costs a (slots, K+1)-shaped
+        forward nothing)."""
         S, K = self.engine.slots, self.spec_k
         drafts = np.zeros((S, K), np.int32)
         dlen = np.zeros((S,), np.int32)
@@ -1211,14 +1243,15 @@ class Scheduler:
         self.stats["spec_proposed"] += int(dlen.sum())
         if not dlen.any():
             self.state, toks = self.engine.decode(self.state, active=mask)
-            return np.asarray(toks)[:, None], mask.astype(np.int32)
+            toks, moe = self._fetch(toks)
+            return toks[:, None], mask.astype(np.int32), moe
         with self.tracer.span("spec_verify", drafted=int(dlen.sum())):
             self.state, emitted, consumed = self.engine.verify(
                 self.state, drafts, dlen, mask)
-            emitted, consumed = np.asarray(emitted), np.asarray(consumed)
+            emitted, consumed, moe = self._fetch(emitted, consumed)
         self.stats["spec_steps"] += 1
         self.stats["spec_accepted"] += int(consumed[mask].sum() - mask.sum())
-        return emitted, consumed
+        return emitted, consumed, moe
 
     # -- the serving loop --------------------------------------------------
     def run(self, requests: Sequence[Request]) -> Dict[int, List[int]]:
@@ -1394,14 +1427,15 @@ class Scheduler:
                             len(r.prompt) + len(r.generated) - 1
                             for r in active.values())]))
                 if self.spec_k:
-                    emitted, consumed = self._spec_round(active, mask)
+                    emitted, consumed, moe = self._spec_round(active, mask)
                 else:
                     self.state, toks = self.engine.decode(self.state,
                                                           active=mask)
-                    emitted = np.asarray(toks)[:, None]
+                    toks, moe = self._fetch(toks)
+                    emitted = toks[:, None]
                     consumed = np.ones((self.engine.slots,), np.int32)
                 now = time.perf_counter()   # emitted is host -> synced
-                self.tracer.end(h_dec, at=now)
+                self.tracer.end(h_dec, at=now, **moe)
                 self.stats["decode_s"] += now - t0
                 self.stats["decode_steps"] += 1
                 self.stats["decode_slot_steps"] += len(active)

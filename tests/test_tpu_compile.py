@@ -88,3 +88,34 @@ def test_paged_attention_compiles_natively(one_chip, arch, shape):
     # how a device trace names it (``bench/kernels.py``: ^paged_attention)
     assert re.search(r"%paged_attention(\.\d+)? = [^\n]*tpu_custom_call",
                      text)
+
+
+# the served qwen3-moe-30b-a3b cell: hidden 2,048, expert hidden 768, 128
+# experts, top-8; (token, expert) pairs of a 64-slot decode step and of a
+# 256-token prefill chunk; gate/up (d -> f) and down (f -> d)
+MOE_ROWS = {"decode": 64 * 8, "chunk": 256 * 8}
+MOE_PROJ = {"gate_up": (2048, 768), "down": (768, 2048)}
+
+
+@pytest.mark.parametrize("proj", list(MOE_PROJ))
+@pytest.mark.parametrize("rows", list(MOE_ROWS))
+def test_moe_gmm_compiles_natively(one_chip, rows, proj):
+    """The served layer reads its experts in place from the 6-layer stack
+    (768 experts, this layer's 128 holding rows)."""
+    from repro.kernels.moe_gmm import ops
+    k, n = MOE_PROJ[proj]
+
+    def spec(shp, dtype):
+        return jax.ShapeDtypeStruct(shp, dtype, sharding=one_chip)
+
+    def moe_gmm(x, w, sizes, layer):    # named as the program's wrapper
+        return ops.grouped_matmul(x, w, sizes, layer, interpret=False)
+
+    compiled = jax.jit(moe_gmm).lower(
+        spec((MOE_ROWS[rows], k), jnp.bfloat16),
+        spec((6, 128, k, n), jnp.bfloat16),
+        spec((128,), jnp.int32), spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    # the custom call takes its enclosing jit's name, which is how a
+    # device trace names it (the expert-layer readers match ^moe_gmm)
+    assert re.search(r"%moe_gmm(\.\d+)? = [^\n]*tpu_custom_call", text)
