@@ -10,7 +10,7 @@ reuses.  What differs:
   dtype, and the engine takes them as they are;
 * each engine step of the traced window also carries the expert layer's
   counts of its span (``expert_pairs``, ``experts_touched``), which the
-  expert-layer readers take from ``work``;
+  expert-layer readers take from ``work`` (``add_expert_counts``);
 * the check runs the served tokens through ``bench/reference/moe_lm.py``
   and compares the mean of the served tokens' logit gaps
   (``logit_gap_mean``), not the widest: top-k routing is discrete, so a
@@ -94,8 +94,10 @@ def make_params(pcfg, seed: int):
 
 def build(cfg: dict, t: dict, seed: int, pcfg=None):
     """As ``serve_lm.build``, over the file's depth and bfloat16 weights,
-    and warming the whole-prompt insert too: a prompt of exactly one
-    chunk is inserted whole."""
+    and warming one request of each prompt length the traffic has: a
+    prompt of at most one chunk is inserted whole, a longer one in
+    chunks and a shorter last chunk, so every insert and chunk shape the
+    traffic uses is compiled."""
     from repro.obs import Tracer
     from repro.serve import InferenceEngine, Request, Scheduler
     pcfg = pcfg or program_config(cfg)
@@ -107,8 +109,8 @@ def build(cfg: dict, t: dict, seed: int, pcfg=None):
     state = engine.init_state(make_params(pcfg, seed))
     tracer = Tracer()
     sched = Scheduler(engine, state, tracer=tracer)
-    warm = [Request(rid=-1 - i, max_new=2, prompt=np.zeros(
-        n * t["prefill_chunk"], np.int32)) for i, n in enumerate((2, 1))]
+    warm = [Request(rid=-1 - i, max_new=2, prompt=np.zeros(L, np.int32))
+            for i, L in enumerate(sorted({L for L, _ in lm.sizes(t)}))]
     sched.run(warm)
     for n in sorted({-(-(L + o) // t["page_size"])
                      for L, o in lm.sizes(t)}):
@@ -119,55 +121,35 @@ def build(cfg: dict, t: dict, seed: int, pcfg=None):
     return pcfg, engine, sched, tracer
 
 
-def expert_work(events, lo: float, hi: float):
-    """The expert counts of the engine steps ``serve_lm.work`` lists for
-    [lo, hi], in its order."""
-    return [{k: e["args"].get(k, 0) for k in ("expert_pairs",
-                                              "experts_touched")}
-            for e in events if e.get("ph") == "X" and e["tid"] == "sched"
-            and e["name"] in ("decode_step", "prefill_chunk")
-            and lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
-
-
 def serve(engine, sched, tracer, reqs, t: dict, seconds: float,
           trace_dir, compiles=None) -> dict:
     """``serve_lm.serve``, with each traced step's expert counts."""
-    win = lm.Window(engine, reqs[:t["slots"]], seconds, trace_dir,
-                    t.get("trace_seconds", seconds), compiles)
-    try:
-        sched.run(reqs)
-        raise RuntimeError("the backlog drained before the window closed")
-    except lm.WindowClosed:
-        pass
-    finally:
-        win.close()
-        del engine.decode, engine.insert_chunk     # unwrap
-    events = tracer.events()
-    us = lambda s: (s - tracer.t0) * 1e6              # noqa: E731
-    lo, hi = us(win.t_start), us(win.t_stop)
-    tl = lm.timelines(events, reqs)
-    in_win = {rid: [x for x in ts if lo < x <= hi] for rid, ts in tl.items()}
-    gaps = [b - a for ts in tl.values() for a, b in zip(ts, ts[1:])
-            if lo < a and b <= hi]
-    vocab = engine.cfg.vocab_size
-    out = {"t_start": win.t_start, "window_s": win.t_stop - win.t_start,
-           "compiles_in_window": win.compiled,
-           "events": events, "tl": tl, "lo": lo, "hi": hi,
-           "tokens": sum(len(v) for v in in_win.values()),
-           "itl_p95_ms": float(np.percentile(np.asarray(gaps) / 1e3, 95))
-           if gaps else float("nan"),
-           "attempted": sum(1 for v in in_win.values() if v),
-           "failed": sum(1 for r in reqs if in_win.get(r.rid) and any(
-               not 0 <= x < vocab for x in r.generated))}
-    if win.trace_t:
-        tlo, thi = us(win.trace_t[0]), us(win.trace_t[1])
-        work = lm.work(events, reqs, tl, tlo, thi)
-        for w, x in zip(work, expert_work(events, tlo, thi)):
-            w.update(x)
-        out.update(work=work, host_ms=lm.host_ms(events, tlo, thi))
-    else:
-        out["host_ms"] = lm.host_ms(events, lo, hi)
-    return out
+    res = lm.serve(engine, sched, tracer, reqs, t, seconds, trace_dir,
+                   compiles)
+    if "work" in res:
+        add_expert_counts(res["work"], res["events"], reqs, res["tl"])
+    return res
+
+
+def add_expert_counts(work, events, reqs, tl) -> None:
+    """Copy each engine step's expert counts (``expert_pairs``,
+    ``experts_touched``: its span's args) onto its entry of ``work``,
+    what ``serve_lm.work`` lists for the traced window.  Those steps are
+    a run of consecutive ones among every step of the serve, whose
+    entries differ step by step (each decode step's keys, each chunk's
+    start): the run is found in the whole list by its entries."""
+    every = lm.work(events, reqs, tl, -np.inf, np.inf)
+    counts = [{k: e["args"].get(k, 0) for k in ("expert_pairs",
+                                                "experts_touched")}
+              for e in events if e.get("ph") == "X" and e["tid"] == "sched"
+              and e["name"] in ("decode_step", "prefill_chunk")]
+    n = len(work)
+    for k in range(len(every) - n + 1):
+        if every[k:k + n] == work:
+            for w, c in zip(work, counts[k:k + n]):
+                w.update(c)
+            return
+    raise RuntimeError("the traced steps are no run of the serve's steps")
 
 
 def token_gaps(params, c: dict, t: dict, sample, quant=None) -> np.ndarray:
@@ -244,7 +226,7 @@ def run(*, cfg, traffic, cell, seed, seconds, trace_dir, t0, device,
 def capacity_layer(params, cfg, x, active=None):
     """The planted fault: the expert layer the program served with before
     its dropless layer, which keeps at most ceil(1.25 * tokens * k / E)
-    pairs an expert per row (a 128-token chunk: 10) and drops the rest."""
+    pairs an expert per row (a 256-token chunk: 20) and drops the rest."""
     import jax.numpy as jnp
     from repro.models import moe
     if "layer" in params:           # inside the layer scan: this layer's

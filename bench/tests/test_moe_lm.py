@@ -26,8 +26,7 @@ SMALL_TRAFFIC = dict(slots=4, max_len=160, num_pages=40, prefill_chunk=32,
                      prompt={"min": 32, "max": 128, "multiple": 32},
                      output={"min": 8, "max": 32, "multiple": 16},
                      strata=4, pair_stride=1, order_seed=0,
-                     blocks=40,
-                     check={"min_tokens": 40})
+                     blocks=100)
 
 
 def _cut(monkeypatch, d_model=256, num_layers=2, experts=16, top_k=4,
@@ -103,16 +102,29 @@ def test_program_logits_match_reference(small):
 
 
 def _line(small, patch=None):
+    """A run of the cut cell whose window is long enough that the check's
+    sample holds the cell's ``min_tokens`` served tokens: the limit is
+    set for a mean over that many, and over a few dozen a sound run's
+    mean swings past it with the sample's requests (5% of tokens here
+    lie off the reference's argmax, by up to 0.7)."""
     bench, _, _, t = small
     if patch:
         patch()
+    sampled, take = [], rs.lm.check_sample
+
+    def check_sample(*a):
+        sample = take(*a)
+        sampled.append(sum(len(r.generated) for r in sample))
+        return sample
     buf = io.StringIO()
-    with redirect_stdout(buf):
+    with pytest.MonkeyPatch.context() as mp, redirect_stdout(buf):
+        mp.setattr(rs.lm, "check_sample", check_sample)
         rc = run.main(["--workload", CELL, "--seed", str(SEED),
-                       "--seconds", "0.5", "--trace", "0"],
+                       "--seconds", "8", "--trace", "0"],
                       bench=bench, require_tpu=False,
                       overrides=SMALL_TRAFFIC)
     assert rc == 0
+    assert sampled[0] >= t["check"]["min_tokens"], sampled
     return json.loads(buf.getvalue().strip().splitlines()[-1])
 
 
@@ -212,3 +224,36 @@ def test_expert_readers_on_a_written_trace():
                   trace=dict(tr, ops={"paged_attention.3": 3e-4}))
     assert _reader("moe_gmm_roofline")(parent) is None
     assert _reader("moe_share.serve")(parent) is None
+
+
+def test_expert_counts_reach_the_traced_steps():
+    """``add_expert_counts`` finds the traced window's engine steps (what
+    ``serve_lm.work`` lists for it) among every step of the serve by their
+    entries, and copies each step's span counts onto its entry."""
+    from repro.serve import Request
+
+    def step(name, ts, **args):
+        return {"ph": "X", "tid": "sched", "name": name, "ts": ts,
+                "dur": 1.0, "args": args}
+    events = [step("prefill_chunk", 0, cursor=0, tokens=8, expert_pairs=64,
+                   experts_touched=10),
+              step("prefill_chunk", 2, cursor=8, tokens=2, expert_pairs=16,
+                   experts_touched=7),
+              {"ph": "X", "tid": "rid0", "name": "decode", "ts": 3.0,
+               "dur": 7.0, "args": {"rid": 0}},
+              step("decode_step", 4, expert_pairs=8, experts_touched=5),
+              step("decode_step", 6, expert_pairs=8, experts_touched=6),
+              step("decode_step", 8, expert_pairs=8, experts_touched=4)]
+    r = Request(rid=0, max_new=4, prompt=np.zeros(10, np.int32))
+    r.generated = [1, 2, 3, 4]
+    tl = rs.lm.timelines(events, [r])
+    work = rs.lm.work(events, [r], tl, 3.5, 7.5)
+    rs.add_expert_counts(work, events, [r], tl)
+    assert work == [
+        {"kind": "decode", "keys": [11], "expert_pairs": 8,
+         "experts_touched": 5},
+        {"kind": "decode", "keys": [12], "expert_pairs": 8,
+         "experts_touched": 6}]
+    with pytest.raises(RuntimeError):
+        rs.add_expert_counts([{"kind": "decode", "keys": [99]}], events,
+                             [r], tl)

@@ -6,7 +6,8 @@ kernel, so no (B, n*ps, Hkv, D) gathered copy of the cache ever
 materializes.  One kernel covers the three serve shapes: single-token
 decode (T=1), speculative verify (T=k+1), and chunked prefill (T=chunk).
 
-Grid: (B,), one step per slot row.  A row walks only its live pages:
+Grid: (B, nq), one step per slot row and tile of its query rows.  A
+row walks only its live pages:
 the wrapper derives each row's extent, the logical pages
 ``[first, end)`` its queries can see, from ``qpos`` (and the window),
 and an inactive row gets an empty extent.  The extents and the page
@@ -24,7 +25,11 @@ read as -1, so stale VMEM never reaches ``p @ v``.
 
 The online-softmax state lives in f32 VMEM scratch keyed by (head,
 flattened T*G query row); the kernel loops the heads statically, and
-each block feeds the MXU one (T*G, ppb*ps) score tile per head.
+each block feeds the MXU one (TQ, ppb*ps) score tile per head.  That
+state, and the q and out blocks, grow with the T*G query rows, so the
+wrapper splits them into ``nq`` tiles of ``TQ`` rows (``q_tiles``) that
+fit the scoped VMEM a grid step may use; each tile walks the row's
+pages again.  Decode and verify rows are always one tile.
 
 Mosaic layout: the last two dims of every VMEM block must be (8, 128)
 multiples or the whole array dims, and a DMA moves whole lane tiles.  So
@@ -54,6 +59,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128                     # a vector register's lanes: keys per block
+#: VMEM bytes of per-query-row blocks, scratch and temporaries one grid
+#: step may hold (``row_bytes`` each): what the 16 MiB of scoped VMEM a
+#: v5e gives a kernel by default leaves after the double-buffered K/V
+#: blocks (1 MiB at 4 KV heads of 256)
+ROW_BUDGET = 29 << 19
 
 
 def pages_per_block(ps: int, n: int) -> int:
@@ -81,6 +91,30 @@ def page_extent(qmin, qmax, *, ps: int, n: int, window: int = 0):
     first = xp.maximum((qmin - window + 1) // ps, 0) if window \
         else xp.zeros_like(qmax)
     return first, xp.minimum(qmax // ps + 1, n)
+
+
+def row_bytes(Hkv: int, D: int, itemsize: int) -> int:
+    """VMEM one flattened query row costs a grid step: its q and out rows
+    (double-buffered), its position (a lane-padded int32, double-buffered),
+    its running max, denominator (lane-padded) and f32 accumulator, and
+    three f32 rows of a block's ``LANES`` keys a head for the scores and
+    their temporaries.  Compiled for a v5e, a 256-token chunk takes 9.95
+    MiB at 2 KV heads and group 6 (one tile), and 11.2 MiB a tile at 4 KV
+    heads and group 8 (three tiles of 688 rows; 19.15 MiB as one)."""
+    return (4 * Hkv * D * itemsize + 2 * LANES * 4
+            + Hkv * (2 * LANES + D) * 4 + 3 * Hkv * LANES * 4)
+
+
+def q_tiles(TG: int, Hkv: int, D: int, itemsize: int) -> tuple:
+    """(nq, TQ): the tiles the kernel splits a row's ``TG`` = T*G query
+    rows into, and the rows of each (a multiple of 8 when nq > 1; the
+    last tile is padded).  One tile while ``TG`` rows fit
+    ``ROW_BUDGET``."""
+    fit = ROW_BUDGET // row_bytes(Hkv, D, itemsize) // 8 * 8
+    if TG <= fit:
+        return 1, TG
+    nq = -(-TG // max(fit, 8))
+    return nq, (-(-TG // nq) + 7) // 8 * 8
 
 
 def walked_pages(first, end, *, ppb: int, n: int):
@@ -159,7 +193,7 @@ def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    qp = qp_ref[0]                                              # (T*G, 1)
+    qp = qp_ref[0]                                              # (TQ, 1)
     D = q_ref.shape[-1]
 
     @pl.when(nblk > 0)
@@ -180,11 +214,11 @@ def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
                     c.wait()
 
         kp = positions(i, buf)                                  # (1, K)
-        mask = (kp >= 0) & (kp <= qp)                           # (T*G, K)
+        mask = (kp >= 0) & (kp <= qp)                           # (TQ, K)
         if window:
             mask = mask & (qp - kp < window)
         for h in range(Hkv):
-            q = q_ref[0, h]                                     # (T*G, D)
+            q = q_ref[0, h]                                     # (TQ, D)
             k = kbuf[buf, :, h, :]                              # (K, D)
             if q.dtype != k.dtype:
                 q, k = q.astype(jnp.float32), k.astype(jnp.float32)
@@ -200,7 +234,7 @@ def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
                 s = softcap * jnp.tanh(s / softcap)
             s = jnp.where(mask, s, NEG_INF)
 
-            m_prev = m_ref[h]                                   # (T*G, 1)
+            m_prev = m_ref[h]                                   # (TQ, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
@@ -214,7 +248,7 @@ def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
 
     jax.lax.fori_loop(0, nblk, block, 0)
 
-    l = l_ref[...]                                              # (Hkv, T*G, 1)
+    l = l_ref[...]                                              # (Hkv, TQ, 1)
     out = acc_ref[...] / jnp.maximum(l, 1e-30)
     out = jnp.where(l > 0, out, 0.0)                            # no attendable key
     o_ref[0] = out.astype(o_ref.dtype)
@@ -242,6 +276,7 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
     P, ps = pos_pool.shape
     n = page_rows.shape[1]
     TG = T * G
+    nq, TQ = q_tiles(TG, Hkv, D, q.dtype.itemsize)
     ppb = pages_per_block(ps, n)
     first, end = page_extent(qpos.min(axis=1), qpos.max(axis=1), ps=ps,
                              n=n, window=window)
@@ -249,6 +284,10 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
         end = jnp.where(active, end, first)
     qh = q.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, TG, D)
     qrow = jnp.repeat(qpos, G, axis=1)[:, :, None]              # row t*G+g
+    pad = nq * TQ - TG          # padded rows sit at position -1: no key
+    if pad:
+        qh = jnp.pad(qh, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        qrow = jnp.pad(qrow, ((0, 0), (0, pad), (0, 0)), constant_values=-1)
     rpp = max(1, _pos_width(ps) // LANES)
 
     kern = functools.partial(_kernel, Hkv=Hkv, n=n, ps=ps, ppb=ppb,
@@ -258,27 +297,28 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B,),
+            grid=(B, nq),
             in_specs=[
-                pl.BlockSpec((1, Hkv, TG, D), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, TG, 1), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv, TQ, D), lambda b, i, *_: (b, 0, i, 0)),
+                pl.BlockSpec((1, TQ, 1), lambda b, i, *_: (b, i, 0)),
                 hbm, hbm, hbm,
             ],
-            out_specs=pl.BlockSpec((1, Hkv, TG, D),
-                                   lambda b, *_: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, Hkv, TQ, D),
+                                   lambda b, i, *_: (b, 0, i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, ppb * ps, Hkv, D), k_pool.dtype),
                 pltpu.VMEM((2, ppb * ps, Hkv, D), v_pool.dtype),
                 pltpu.VMEM((2, ppb * rpp, LANES), jnp.int32),
                 pltpu.SemaphoreType.DMA((3, 2)),
-                pltpu.VMEM((Hkv, TG, 1), jnp.float32),  # running max
-                pltpu.VMEM((Hkv, TG, 1), jnp.float32),  # running denom
-                pltpu.VMEM((Hkv, TG, D), jnp.float32),  # output accumulator
+                pltpu.VMEM((Hkv, TQ, 1), jnp.float32),  # running max
+                pltpu.VMEM((Hkv, TQ, 1), jnp.float32),  # running denom
+                pltpu.VMEM((Hkv, TQ, D), jnp.float32),  # output accumulator
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, TG, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, nq * TQ, D), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(page_rows, first.astype(jnp.int32), end.astype(jnp.int32), qh, qrow,
       k_pool, v_pool, _pos_rows(pos_pool))
+    out = out[:, :, :TG]
     return out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)

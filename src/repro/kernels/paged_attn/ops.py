@@ -1,11 +1,12 @@
 """jit'd wrapper: GQA head-grouping reshape around the fused kernel.
 
-Unlike ``local_attn``'s wrapper there is NO sequence padding here — the
-grid never tiles the query axis (T is a whole block) and the KV axis is
-walked in blocks of whole pool pages (``pages_per_block``: 128 keys at
-16-token pages) over each row's live extent, so no padded key can reach
-the softmax: every key the kernel scores is a real pool entry or a page
-slot it masks, and emptiness is carried by pos = -1 / page id = -1 alone.
+Unlike ``local_attn``'s wrapper there is NO key padding here — the KV
+axis is walked in blocks of whole pool pages (``pages_per_block``: 128
+keys at 16-token pages) over each row's live extent, so no padded key can
+reach the softmax: every key the kernel scores is a real pool entry or a
+page slot it masks, and emptiness is carried by pos = -1 / page id = -1
+alone.  Only a long chunk's query rows are tiled (``q_tiles``); padded
+query rows sit at position -1, see no key, and are sliced off.
 """
 from __future__ import annotations
 

@@ -255,34 +255,101 @@ def moe_dropless(params, cfg: ModelConfig, x: jax.Array,
     expert and of the counts; its output is then the shared expert's
     alone (0 without one).  Inside a layer scan the expert weights come
     stacked over the scanned layers with ``params["layer"]`` this layer's
-    index, and are read in place (``kernels/moe_gmm``)."""
-    m = cfg.moe
-    B, S, d = x.shape
-    T, K = B * S, m.top_k
+    index, and are read in place (``kernels/moe_gmm``).  Under a mesh of
+    more than one device the layer runs expert-parallel
+    (``_dropless_on_mesh``)."""
+    from repro.distributed import sharding as shd
+    ctx = getattr(shd._CTX, "val", None)
     with jax.named_scope("moe"):
-        with jax.named_scope("route"):
-            _, topk_idx, topk_w = route(params["router"], x, m)
-            flat_e = topk_idx.reshape(T * K)
-            if active is not None:      # past the last expert: sorts last
-                flat_e = jnp.where(jnp.repeat(active, S * K), flat_e,
-                                   m.num_experts)
-            order = jnp.argsort(flat_e, stable=True)
-            counts = jnp.bincount(flat_e, length=m.num_experts
-                                  ).astype(jnp.int32)
-            rows = x.reshape(T, d)[order // K]                  # (T*K, d)
-        with jax.named_scope("experts"):
-            layer = params.get("layer")
-            g = moe_gmm(rows, params["w_gate"], counts, layer)
-            u = moe_gmm(rows, params["w_up"], counts, layer)
-            h = _act(g, cfg.act) * u
-            out = moe_gmm(h, params["w_down"], counts, layer)   # (T*K, d) f32
-            if active is not None:      # rows past the counts: undefined
-                out = jnp.where((jnp.arange(T * K) < counts.sum())[:, None],
-                                out, 0.0)
-        with jax.named_scope("combine"):
-            per_pair = out[jnp.argsort(order)].reshape(T, K, d)
-            y = jnp.einsum("tkd,tk->td", per_pair, topk_w.reshape(T, K))
-            y = y.astype(x.dtype).reshape(B, S, d)
-        if m.num_shared:
+        if ctx is not None and ctx[0].size > 1:
+            y, counts = _dropless_on_mesh(params, cfg, x, active, *ctx)
+        else:
+            y, counts = _dropless(params, cfg, x, active)
+        if cfg.moe.num_shared:
             y = y + mlp_block(params["shared"], cfg.act, x)
     return y, counts
+
+
+def _dropless(params, cfg: ModelConfig, x: jax.Array, active, first=0,
+              held: Optional[int] = None):
+    """The routed experts' part of ``moe_dropless`` for experts ``[first,
+    first + held)`` (default all), whose weights ``params`` holds: y sums
+    only those experts' outputs; counts are over every expert."""
+    m = cfg.moe
+    held = held or m.num_experts
+    B, S, d = x.shape
+    T, K = B * S, m.top_k
+    with jax.named_scope("route"):
+        _, topk_idx, topk_w = route(params["router"], x, m)
+        flat_e = topk_idx.reshape(T * K)
+        if active is not None:      # past the last expert: sorts last
+            flat_e = jnp.where(jnp.repeat(active, S * K), flat_e,
+                               m.num_experts)
+        counts = jnp.bincount(flat_e, length=m.num_experts).astype(jnp.int32)
+        mine = counts
+        if held < m.num_experts:    # another shard's experts sort last
+            local = flat_e - first
+            flat_e = jnp.where((local >= 0) & (local < held), local, held)
+            mine = jnp.bincount(flat_e, length=held).astype(jnp.int32)
+        order = jnp.argsort(flat_e, stable=True)
+        rows = x.reshape(T, d)[order // K]                      # (T*K, d)
+    with jax.named_scope("experts"):
+        layer = params.get("layer")
+        g = moe_gmm(rows, params["w_gate"], mine, layer)
+        u = moe_gmm(rows, params["w_up"], mine, layer)
+        h = _act(g, cfg.act) * u
+        out = moe_gmm(h, params["w_down"], mine, layer)         # (T*K, d) f32
+        if active is not None or held < m.num_experts:
+            # rows past the counts are undefined
+            out = jnp.where((jnp.arange(T * K) < mine.sum())[:, None],
+                            out, 0.0)
+    with jax.named_scope("combine"):
+        per_pair = out[jnp.argsort(order)].reshape(T, K, d)
+        y = jnp.einsum("tkd,tk->td", per_pair, topk_w.reshape(T, K))
+        y = y.astype(x.dtype).reshape(B, S, d)
+    return y, counts
+
+
+def _dropless_on_mesh(params, cfg: ModelConfig, x: jax.Array, active,
+                      mesh, rules):
+    """``_dropless`` expert-parallel under ``shard_map``: where the rules
+    shard experts over "model" (and it divides them) each model shard
+    holds ``E / ways`` experts, routes its rows over all of them, runs
+    its own experts' pairs, and one ``psum`` over "model" combines; rows
+    split over the batch axes when they divide B (a decode step's slots;
+    a one-row chunk is replicated).  Counts sum over the row shards."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import suspend_logical_sharding
+    m = cfg.moe
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    ways = sizes.get("model", 1)
+    split = rules.get("experts") == "model" and ways > 1 \
+        and m.num_experts % ways == 0
+    held = m.num_experts // ways if split else m.num_experts
+    row_ax = tuple(a for a in ("pod", "data") if sizes.get(a, 1) > 1)
+    if x.shape[0] % int(np.prod([sizes[a] for a in row_ax])):
+        row_ax = ()
+    rows = row_ax or None
+    p = {k: params[k] for k in ("router", "w_gate", "w_up", "w_down",
+                                "layer") if k in params}
+    stacked = "layer" in p
+    spec = {k: P(*((None,) * stacked + ("model" if split else None,)
+                   + (None, None))) for k in ("w_gate", "w_up", "w_down")}
+    spec.update(router=P(), **({"layer": P()} if stacked else {}))
+    args, specs = (p, x), (spec, P(rows, None, None))
+    if active is not None:
+        args, specs = args + (active,), specs + (P(rows),)
+
+    def local(p, x, active=None):
+        with suspend_logical_sharding():
+            first = jax.lax.axis_index("model") * held if split else 0
+            y, counts = _dropless(p, cfg, x, active, first, held)
+        if split:
+            y = jax.lax.psum(y, "model")
+        if row_ax:
+            counts = jax.lax.psum(counts, row_ax)
+        return y, counts
+
+    return jax.shard_map(local, mesh=mesh, in_specs=specs,
+                         out_specs=(P(rows, None, None), P()),
+                         check_vma=False)(*args)

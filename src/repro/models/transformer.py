@@ -76,6 +76,14 @@ def _ffn_kind(cfg: ModelConfig, kind: LayerKind, *, in_prefix: bool) -> str:
     return "dense" if cfg.d_ff else "none"
 
 
+def expert_layers(cfg: ModelConfig) -> int:
+    """Layers whose FFN is the expert layer: the rows of a serving step's
+    expert counts."""
+    _, pat, n_rep, suffix = stack_plan(cfg)
+    return sum(_ffn_kind(cfg, k, in_prefix=False) == "moe"
+               for k in list(pat) * n_rep + list(suffix))
+
+
 def _layer_params(mk: ParamFactory, cfg: ModelConfig, kind: LayerKind,
                   ffn: str):
     d = cfg.d_model

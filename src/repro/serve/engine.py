@@ -104,12 +104,6 @@ class InferenceEngine:
         self._jit_cache: dict = {}
         self._state_shardings = None
         self._has_rec: Optional[bool] = None
-        #: the last step's per-expert pair counts, (expert layers, E) int32
-        #: on the device, for a model with experts: what each decode,
-        #: verify and prefill-chunk step returns beside its tokens, for the
-        #: caller to fetch with them.  None after any other step, and
-        #: always None without experts.
-        self.last_expert_counts = None
 
     @property
     def mesh(self):
@@ -491,7 +485,8 @@ class InferenceEngine:
             cache=cache,
             positions=state.positions.at[slot].set(end),
             last_tok=state.last_tok.at[slot].set(tok[0]),
-        ), tok, counts
+            expert_counts=counts,
+        ), tok
 
     def _decode_fn(self, state: InferenceState):
         logits, cache = tfm.decode_step(
@@ -514,7 +509,8 @@ class InferenceEngine:
             positions=state.positions + active.astype(jnp.int32),
             last_tok=jnp.where(active, tok, state.last_tok),
             tok_presence=presence,
-        ), tok, counts
+            expert_counts=counts,
+        ), tok
 
     def _verify_fn(self, state: InferenceState, drafts: jax.Array,
                    draft_len: jax.Array, active: jax.Array):
@@ -590,7 +586,8 @@ class InferenceEngine:
             positions=state.positions + consumed,
             last_tok=jnp.where(active, last, state.last_tok),
             tok_presence=presence,
-        ), target, consumed, counts
+            expert_counts=counts,
+        ), target, consumed
 
     def _active_sharding(self):
         return NamedSharding(self.mesh, resolve_pspec(
@@ -624,8 +621,8 @@ class InferenceEngine:
                              self._active_sharding())
                 else:
                     in_sh = (st_sh, self._active_sharding())
-                out_sh = (st_sh,) + (None,) * {
-                    "verify": 3, "chunk": 2, "decode_paged": 2}.get(kind, 1)
+                out_sh = (st_sh, None, None) if kind == "verify" \
+                    else (st_sh, None)
                 jfn = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                               donate_argnums=donate)
             self._jit_cache[key] = jfn
@@ -648,7 +645,6 @@ class InferenceEngine:
         # annotate() scopes are live only inside an open jax.profiler
         # window (--profile-dir): host phase names then line up with the
         # device timeline; otherwise they are null contexts
-        self.last_expert_counts = None
         with profiler.annotate("serve.insert"):
             return self._run(jfn, state, inputs,
                              jnp.asarray(slot, jnp.int32))
@@ -661,15 +657,15 @@ class InferenceEngine:
         (state, greedy token (1,)) — the token is meaningful only for the
         final chunk of a prompt.  Jit-cached per chunk shape, so a prompt
         split into fixed-size chunks compiles twice at most (body +
-        remainder).  Sets ``last_expert_counts``."""
+        remainder).  The returned state's ``expert_counts`` are the
+        chunk's."""
         assert self.paged, "insert_chunk requires the paged cache"
         inputs = {k: jnp.asarray(v) for k, v in inputs.items()}
         jfn = self._get_jit("chunk", state, inputs)
         with profiler.annotate("serve.insert_chunk"):
-            state, tok, self.last_expert_counts = self._run(
-                jfn, state, inputs, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(pos_start, jnp.int32))
-        return state, tok
+            return self._run(jfn, state, inputs,
+                             jnp.asarray(slot, jnp.int32),
+                             jnp.asarray(pos_start, jnp.int32))
 
     def verify(self, state: InferenceState, drafts, draft_len, active):
         """One fused speculative decode step over ALL slots.  ``drafts``
@@ -681,8 +677,8 @@ class InferenceEngine:
         ``emitted[s, :consumed[s]]`` — its own continuation under its
         sampling params (argmax for greedy slots), bit-identical to
         ``consumed[s]`` successive :meth:`decode` calls — and advanced
-        its position by ``consumed[s]``.  Jit-cached per K.  Sets
-        ``last_expert_counts``."""
+        its position by ``consumed[s]``.  Jit-cached per K.  The returned
+        state's ``expert_counts`` are the step's."""
         if not self.paged:
             raise ValueError("speculative verification writes draft KV "
                              "through page tables; build the engine with "
@@ -691,10 +687,9 @@ class InferenceEngine:
         drafts = jnp.asarray(drafts, jnp.int32)
         jfn = self._get_jit("verify", state, {"drafts": drafts})
         with profiler.annotate("serve.verify"):
-            state, emitted, consumed, self.last_expert_counts = self._run(
-                jfn, state, drafts, jnp.asarray(draft_len, jnp.int32),
-                jnp.asarray(active, bool))
-        return state, emitted, consumed
+            return self._run(jfn, state, drafts,
+                             jnp.asarray(draft_len, jnp.int32),
+                             jnp.asarray(active, bool))
 
     def lower_decode(self, state: InferenceState) -> jax.stages.Lowered:
         """Lower the fused all-slot decode step for ``state`` (arrays or
@@ -718,20 +713,18 @@ class InferenceEngine:
         clears their page rows, turning stray writes into dropped
         scatters), but the mask is REQUIRED while any slot is
         mid-chunked-prefill — only the caller knows those slots, and an
-        unmasked decode would advance their recurrent state.  Sets
-        ``last_expert_counts`` (the active rows' pairs)."""
+        unmasked decode would advance their recurrent state.  In paged
+        mode the returned state's ``expert_counts`` are the step's (the
+        active rows' pairs)."""
         if self.paged:
             if active is None:
                 active = np.ones((self.slots,), bool)
             jfn = self._get_jit("decode_paged", state)
             with profiler.annotate("serve.decode"):
-                state, tok, self.last_expert_counts = self._run(
-                    jfn, state, jnp.asarray(active, bool))
-            return state, tok
+                return self._run(jfn, state, jnp.asarray(active, bool))
         if active is not None:
             raise ValueError("active masks are a paged-mode feature; the "
                              "contiguous decode advances every slot")
         jfn = self._get_jit("decode", state)
-        self.last_expert_counts = None
         with profiler.annotate("serve.decode"):
             return self._run(jfn, state)

@@ -1170,12 +1170,12 @@ class Scheduler:
         return True
 
     def _fetch(self, *outs):
-        """The step's outputs on the host, with the engine's expert counts
-        in the same transfer where the model has experts.  Returns (*outs,
-        span args): ``expert_pairs``, ``experts_touched`` and
-        ``expert_max_load`` of the step (empty without experts), which are
-        also added to the run's counters."""
-        counts = self.engine.last_expert_counts
+        """The step's outputs on the host, with the expert counts of the
+        state it returned (``expert_counts``; None without experts) in
+        the same transfer.  Returns (*outs, span args): ``expert_pairs``,
+        ``experts_touched`` and ``expert_max_load`` of the step (empty
+        without experts), which are also added to the run's counters."""
+        counts = self.state.expert_counts
         if counts is None:
             return (*(np.asarray(o) for o in outs), {})
         *outs, counts = jax.device_get((*outs, counts))

@@ -51,6 +51,10 @@ class InferenceState(NamedTuple):
     sample_rep: Any = None    # (S,) f32 repetition penalty, 1.0 = off
     sample_key: Any = None    # (S, 2) uint32 raw threefry key data
     tok_presence: Any = None  # (S, padded_vocab) bool
+    # paged mode, a model with experts: the (token, expert) pairs of each
+    # expert layer's experts in the last decode, verify or prefill-chunk
+    # step, (expert layers, E) int32, which each of those steps returns
+    expert_counts: Any = None
 
 
 def _sampling_leaves(slots: int, vocab: int) -> dict:
@@ -111,6 +115,7 @@ def paged_inference_state_axes(cfg: ModelConfig) -> InferenceState:
         last_tok=("batch",),
         page_table=("batch", None),
         **_SAMPLING_AXES,
+        expert_counts=(None, None) if cfg.moe is not None else None,
     )
 
 
@@ -127,6 +132,8 @@ def new_paged_inference_state(params: Any, cfg: ModelConfig, *, slots: int,
         last_tok=jnp.zeros((slots,), jnp.int32),
         page_table=jnp.full((slots, pages_per_slot), -1, jnp.int32),
         **_sampling_leaves(slots, cfg.padded_vocab()),
+        expert_counts=None if cfg.moe is None else jnp.zeros(
+            (tfm.expert_layers(cfg), cfg.moe.num_experts), jnp.int32),
     )
 
 

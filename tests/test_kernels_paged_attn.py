@@ -147,6 +147,57 @@ def test_dead_pages_never_reach_the_math(rng):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+@pytest.mark.parametrize("Hq,Hkv,Tq,tiles", [
+    pytest.param(4, 1, 4, (2, 8), id="two-exact"),
+    pytest.param(4, 2, 7, (2, 8), id="two-padded"),
+    pytest.param(4, 1, 5, (3, 8), id="three-padded"),
+])
+def test_query_tiles_match_one_tile(rng, monkeypatch, Hq, Hkv, Tq, tiles):
+    """A chunk's T*G query rows split into ``nq`` tiles of ``TQ`` (each
+    walking the row's pages again; padded rows at position -1) give the
+    oracle's output, on the "blocks" layout with a window: the row
+    budget is cut to 8 rows so the small case tiles."""
+    from repro.kernels.paged_attn import kernel
+    from repro.kernels.paged_attn.kernel import paged_attention_pallas
+    monkeypatch.setattr(kernel, "ROW_BUDGET", 8 * kernel.row_bytes(
+        Hkv, 16, 4))
+    assert kernel.q_tiles(Tq * Hq // Hkv, Hkv, 16, 4) == tiles
+    L = LAYOUTS["blocks"]
+    cache, rows = _pool_and_slots(rng, L["lens"], Hkv=Hkv, D=16, n=L["n"],
+                                  ps=L["ps"])
+    B, G = len(L["lens"]), Hq // Hkv
+    qpos = jnp.asarray([[p + t for t in range(Tq)] for p in L["qpos0"]],
+                       jnp.int32)
+    active = jnp.asarray(L["active"])
+    q = jnp.asarray(rng.normal(0, 1, (B, Tq, Hkv, G, 16)), jnp.float32)
+    got = paged_attention_pallas(q, cache.k, cache.v, cache.pos, rows, qpos,
+                                 active, window=40)
+    want = paged_attention_ref(q, cache.k, cache.v, cache.pos, rows, qpos,
+                               window=40)
+    want = jnp.where(active[:, None, None, None, None], want, 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("Hkv,G,T,want", [
+    pytest.param(2, 6, 256, (1, 1536), id="qwen2-chunk-256"),
+    pytest.param(2, 6, 512, (2, 1536), id="qwen2-chunk-512"),
+    pytest.param(4, 8, 256, (3, 688), id="qwen3-moe-chunk-256"),
+    pytest.param(4, 8, 1, (1, 8), id="qwen3-moe-decode"),
+    pytest.param(4, 8, 100, (1, 800), id="qwen3-moe-chunk-100"),
+])
+def test_query_tiles_of_the_served_shapes(Hkv, G, T, want):
+    """bf16 queries at head size 128: a row's T*G query rows stay one tile
+    up to ``ROW_BUDGET`` (qwen2's 256-token chunk, every decode), and
+    split into equal tiles past it."""
+    from repro.kernels.paged_attn.kernel import (
+        ROW_BUDGET, q_tiles, row_bytes,
+    )
+    assert q_tiles(T * G, Hkv, 128, 2) == want
+    nq, TQ = want
+    assert TQ * row_bytes(Hkv, 128, 2) <= ROW_BUDGET
+    assert nq * TQ >= T * G
+
+
 def test_walked_pages_rounds_extents_out_to_blocks():
     """The count the scheduler reports and the kernel walks: a 20-page
     extent spans three blocks, the last one short (21 pages a row, no

@@ -29,12 +29,15 @@ CHUNK = 256
 WIDTHS = {
     "qwen2-1.5b": (2, 6, 128, 0, 0.0),
     "gemma2-2b": (4, 2, 256, 4096, 50.0),
+    "qwen3-moe-30b-a3b": (4, 8, 128, 0, 0.0),
 }
 N = -(-MAX_LEN // PS)                    # pages a slot: 36, no multiple of 8
 # the three serve shapes: (slots B, query tokens T, pages a slot n); the
 # decode and verify calls carry an ``active`` mask, a chunk does not
 SHAPES = {"decode": (SLOTS, 1, N), "verify": (SLOTS, SPEC_K + 1, N),
           "chunk": (1, CHUNK, N),
+          # twice the chunk: its query rows split into tiles (``q_tiles``)
+          "chunk-512": (1, 2 * CHUNK, N),
           # the long-document cell's 16 slots, with 531 pages a slot: the
           # last of its 67 blocks of 8 pages is short
           "decode-long": (16, 1, 531)}
