@@ -11,11 +11,15 @@ row walks only its live pages:
 the wrapper derives each row's extent, the logical pages
 ``[first, end)`` its queries can see, from ``qpos`` (and the window),
 and an inactive row gets an empty extent.  The extents and the page
-table ride in scalar-prefetch memory (SMEM).  The K, V and pos pools
-stay in HBM (``memory_space=pl.ANY``); the kernel loops over the row's
+table ride in scalar-prefetch memory (SMEM), with the layer index.  The
+K, V and pos pools stay in HBM (``memory_space=pl.ANY``), K and V as the
+layer scan's whole stacks (L, P, ps, Hkv, D): a Pallas call takes a
+sliced operand as a copy, so the kernel reads layer ``layer``'s pages
+where they lie.  It loops over the row's
 blocks of ``ppb`` pages (``pages_per_block``: 128 keys at 16-token
 pages) with a dynamic trip count, and copies each block's pages into a
-VMEM buffer by one DMA per page (``pool[tab[b, j]]``), double-buffered:
+VMEM buffer by one DMA per page (``pool[layer, tab[b, j]]``),
+double-buffered:
 block i+1's copies are in flight while block i computes.  Blocks are
 aligned to multiples of ``ppb`` in the row, so the walk covers the
 extent rounded out to whole blocks (``walked_pages`` counts exactly
@@ -127,12 +131,13 @@ def walked_pages(first, end, *, ppb: int, n: int):
     return xp.where(end > first, hi - lo, 0)
 
 
-def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
-            kp_hbm, o_ref, kbuf, vbuf, pbuf, sem, m_ref, l_ref, acc_ref, *,
-            Hkv: int, n: int, ps: int, ppb: int, window: int,
+def _kernel(tab_ref, first_ref, end_ref, layer_ref, q_ref, qp_ref, k_hbm,
+            v_hbm, kp_hbm, o_ref, kbuf, vbuf, pbuf, sem, m_ref, l_ref,
+            acc_ref, *, Hkv: int, n: int, ps: int, ppb: int, window: int,
             softcap: float):
     b = pl.program_id(0)
     first, end = first_ref[b], end_ref[b]
+    layer = layer_ref[0]
     blk0 = first // ppb
     nblk = jnp.where(end > first, (end + ppb - 1) // ppb - blk0, 0)
     psw = _pos_width(ps)
@@ -151,10 +156,10 @@ def _kernel(tab_ref, first_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm,
     def copies(s, page, buf):
         keys = pl.ds(s * ps, ps)
         return (
-            pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, keys],
-                                  sem.at[0, buf]),
-            pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, keys],
-                                  sem.at[1, buf]),
+            pltpu.make_async_copy(k_hbm.at[layer, page],
+                                  kbuf.at[buf, keys], sem.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[layer, page],
+                                  vbuf.at[buf, keys], sem.at[1, buf]),
             pltpu.make_async_copy(kp_hbm.at[pl.ds(page * rpp // ppr, rpp)],
                                   pbuf.at[buf, pl.ds(s * rpp, rpp)],
                                   sem.at[2, buf]))
@@ -266,12 +271,23 @@ def _pos_rows(pos_pool):
 
 
 def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
-                           active=None, *, window: int = 0,
+                           active=None, layer=None, *, window: int = 0,
                            softcap: float = 0.0, interpret: bool = True):
     """q (B,T,Hkv,G,D); k/v pool (P,ps,Hkv,D); pos pool (P,ps);
     page_rows (B,n) physical page ids (-1 = unassigned); qpos (B,T)
     absolute query positions; ``active`` (B,) bool or None (all rows):
-    an inactive row walks no page and outputs 0 -> (B,T,Hkv,G,D)."""
+    an inactive row walks no page and outputs 0 -> (B,T,Hkv,G,D).  With
+    ``layer`` the pools are stacks (L,P,ps,Hkv,D) / (L,P,ps) and layer
+    ``layer``'s K/V pages are read in place.  Its positions alone are
+    sliced out (P*ps int32 against the layer's P*ps*Hkv*D keys): on the
+    chip the stacked pos pool keeps its pages in lanes, so a lane-dense
+    view of the whole stack would copy all of it, while the K/V stacks
+    keep D in lanes and a DMA addresses one layer's page in place."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    else:
+        pos_pool = pos_pool[layer]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     B, T, Hkv, G, D = q.shape
     P, ps = pos_pool.shape
     n = page_rows.shape[1]
@@ -296,7 +312,7 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, nq),
             in_specs=[
                 pl.BlockSpec((1, Hkv, TQ, D), lambda b, i, *_: (b, 0, i, 0)),
@@ -318,7 +334,7 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_rows, qpos,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, nq * TQ, D), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(page_rows, first.astype(jnp.int32), end.astype(jnp.int32), qh, qrow,
-      k_pool, v_pool, _pos_rows(pos_pool))
+    )(page_rows, first.astype(jnp.int32), end.astype(jnp.int32), layer, qh,
+      qrow, k_pool, v_pool, _pos_rows(pos_pool))
     out = out[:, :, :TG]
     return out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)

@@ -21,22 +21,24 @@ from repro.kernels.paged_attn.kernel import paged_attention_pallas
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap"))
 def paged_attention_fused(q, k_pool, v_pool, pos_pool, page_rows, qpos,
-                          active=None, *, window: int = 0,
+                          active=None, layer=None, *, window: int = 0,
                           softcap: float = 0.0):
     """q (B,T,Hq,D) vs the page pool -> (B,T,Hq,D), pre-out-projection.
 
     k/v_pool (P,ps,Hkv,D), pos_pool (P,ps) absolute positions (-1 empty),
     page_rows (B,n) per-slot physical page ids (-1 unassigned), qpos
     (B,T) absolute query positions, ``active`` (B,) bool or None: an
-    inactive row reads no page and outputs 0.  ``window=0`` disables the
+    inactive row reads no page and outputs 0.  With ``layer`` the pools
+    are a layer scan's stacks (L,P,ps,Hkv,D) / (L,P,ps) and layer
+    ``layer``'s pages are read in place.  ``window=0`` disables the
     sliding window, ``softcap=0`` disables logit softcapping.
     """
     B, T, Hq, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[-2]
     G = Hq // Hkv
     qr = q.reshape(B, T, Hkv, G, D)
     out = paged_attention_pallas(
         qr, k_pool, v_pool, pos_pool.astype(jnp.int32),
-        page_rows.astype(jnp.int32), qpos.astype(jnp.int32), active,
+        page_rows.astype(jnp.int32), qpos.astype(jnp.int32), active, layer,
         window=window, softcap=softcap, interpret=use_interpret())
     return out.reshape(B, T, Hq, D)

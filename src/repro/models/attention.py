@@ -412,6 +412,13 @@ def decode_attention(params, cfg: ModelConfig, x: jax.Array, cache: KVCache,
 # out-of-bounds page id ``num_pages`` and dropped by XLA (mode="drop");
 # gathers of unassigned pages fill with position -1, which the shared
 # position mask in ``attend_cached`` already treats as empty.
+#
+# Inside the layer scan the pools of the scanned layers stay stacked
+# (L, P, ...) in the scan's carry, and the step functions take ``layer``:
+# each write lands at (layer, page, offset) in the stack, in place, and
+# each read gathers layer ``layer``'s pages from the stack, so no step
+# copies a layer's pool out of the stack or back.  A dropped write stays
+# out of bounds on the page axis, at (layer, P, offset).
 # ---------------------------------------------------------------------------
 class PagedKVCache(NamedTuple):
     k: jax.Array          # (P, page_size, Hkv, D)
@@ -439,17 +446,20 @@ def init_paged_kv_cache(num_pages: int, page_size: int, hkv: int, dh: int,
     )
 
 
-def gather_pages(cache: PagedKVCache, page_rows: jax.Array):
+def gather_pages(cache: PagedKVCache, page_rows: jax.Array, layer=None):
     """page_rows (B, n) -> (k (B, n*ps, Hkv, D), v, pos (B, n*ps)).
 
     Unassigned entries (page id -1) gather as empty: k/v fill 0 and pos
-    fills -1, so downstream masking needs no page-validity plumbing."""
-    P, ps, hkv, dh = cache.k.shape
+    fills -1, so downstream masking needs no page-validity plumbing.  With
+    ``layer`` the pools are stacks (L, P, ...) and layer ``layer``'s pages
+    are gathered straight from the stack."""
+    P, ps, hkv, dh = cache.k.shape[-4:]
     B, n = page_rows.shape
     safe = jnp.where(page_rows >= 0, page_rows, P)              # P = out of bounds
-    k = jnp.take(cache.k, safe, axis=0, mode="fill", fill_value=0)
-    v = jnp.take(cache.v, safe, axis=0, mode="fill", fill_value=0)
-    pos = jnp.take(cache.pos, safe, axis=0, mode="fill", fill_value=-1)
+    idx = safe if layer is None else (layer, safe)
+    k = cache.k.at[idx].get(mode="fill", fill_value=0)
+    v = cache.v.at[idx].get(mode="fill", fill_value=0)
+    pos = cache.pos.at[idx].get(mode="fill", fill_value=-1)
     return (k.reshape(B, n * ps, hkv, dh), v.reshape(B, n * ps, hkv, dh),
             pos.reshape(B, n * ps))
 
@@ -470,13 +480,27 @@ def _page_coords(page_rows: jax.Array, logical: jax.Array, ps: int, P: int,
     return phys, logical % ps, ok
 
 
+def _write_pages(cache: PagedKVCache, phys, off, k, v, pos,
+                 layer=None) -> PagedKVCache:
+    """Scatter K/V rows and their positions to (page, offset), or with
+    ``layer`` to (layer, page, offset) of the stacked pools; writes to the
+    out-of-bounds page are dropped."""
+    idx = (phys, off) if layer is None else (layer, phys, off)
+    return PagedKVCache(
+        cache.k.at[idx].set(k.astype(cache.k.dtype), mode="drop"),
+        cache.v.at[idx].set(v.astype(cache.v.dtype), mode="drop"),
+        cache.pos.at[idx].set(pos, mode="drop"))
+
+
 def paged_attend(params, cfg: ModelConfig, q: jax.Array,
                  cache: PagedKVCache, page_rows: jax.Array,
                  qpos: jax.Array, *,
                  window: Optional[int] = None,
-                 active: Optional[jax.Array] = None) -> jax.Array:
-    """Attend q (B,T,Hq,D) against the page pool through slot page tables
-    page_rows (B,n) and project out.  Dispatches to the fused Pallas
+                 active: Optional[jax.Array] = None,
+                 layer=None) -> jax.Array:
+    """Attend q (B,T,Hq,D) against the page pool (layer ``layer`` of the
+    stacked pools, when given) through slot page tables page_rows (B,n)
+    and project out.  Dispatches to the fused Pallas
     kernel (``kernels.paged_attn``) when ``use_paged_attn_kernel()`` says
     so — the TPU fast path, no gathered cache copy — and otherwise to the
     lax fallback (``gather_pages`` + ``attend_masked``).  Both paths see
@@ -486,11 +510,11 @@ def paged_attend(params, cfg: ModelConfig, q: jax.Array,
     callers must not read an inactive row's output."""
     if use_paged_attn_kernel():
         o = paged_attention_fused(
-            q, cache.k, cache.v, cache.pos, page_rows, qpos, active,
+            q, cache.k, cache.v, cache.pos, page_rows, qpos, active, layer,
             window=int(window) if window else 0,
             softcap=float(cfg.attn_softcap) if cfg.attn_softcap else 0.0)
     else:
-        k_all, v_all, kp = gather_pages(cache, page_rows)
+        k_all, v_all, kp = gather_pages(cache, page_rows, layer)
         o = attend_masked(cfg, q, k_all, v_all, kp, qpos, window=window)
     return out_project(params, o)
 
@@ -504,40 +528,34 @@ def paged_fill_from_prefill(pool: PagedKVCache, ring: KVCache,
     pos = ring.pos[0]                                           # (L,) absolute, -1 empty
     rows = jnp.broadcast_to(page_row, (pos.shape[0],) + page_row.shape)
     phys, off, ok = _page_coords(rows, pos, ps, P)
-    new_k = pool.k.at[phys, off].set(ring.k[0].astype(pool.k.dtype),
-                                     mode="drop")
-    new_v = pool.v.at[phys, off].set(ring.v[0].astype(pool.v.dtype),
-                                     mode="drop")
-    new_pos = pool.pos.at[phys, off].set(pos, mode="drop")
-    return PagedKVCache(new_k, new_v, new_pos)
+    return _write_pages(pool, phys, off, ring.k[0], ring.v[0], pos)
 
 
 def paged_decode_attention(params, cfg: ModelConfig, x: jax.Array,
                            cache: PagedKVCache, page_rows: jax.Array,
                            position: jax.Array, *,
                            window: Optional[int] = None,
-                           active: Optional[jax.Array] = None):
+                           active: Optional[jax.Array] = None,
+                           layer=None):
     """One decode step against the page pool.  x (B,1,d); page_rows (B,n)
     per-slot page tables; position (B,) per-row write index; ``active``
     (B,) bool — inactive rows (free slots, or slots mid-chunked-prefill)
     have their writes dropped so they can never clobber a live page.
+    With ``layer`` the pools are stacks and only that layer's pages are
+    written and read.
 
     Returns (out (B,1,d), new_cache)."""
     B = x.shape[0]
     pos = jnp.broadcast_to(
         jnp.atleast_1d(jnp.asarray(position, jnp.int32)), (B,))
     q, k_new, v_new = qkv_project(params, cfg, x, pos[:, None])
-    P, ps = cache.k.shape[0], cache.k.shape[1]
+    P, ps = cache.pos.shape[-2:]
     with jax.named_scope("kv_write"):
         phys, off, ok = _page_coords(page_rows, pos, ps, P, extra_ok=active)
-        new_k = cache.k.at[phys, off].set(k_new[:, 0].astype(cache.k.dtype),
-                                          mode="drop")
-        new_v = cache.v.at[phys, off].set(v_new[:, 0].astype(cache.v.dtype),
-                                          mode="drop")
-        new_pos = cache.pos.at[phys, off].set(pos, mode="drop")
-    new_cache = PagedKVCache(new_k, new_v, new_pos)
+        new_cache = _write_pages(cache, phys, off, k_new[:, 0], v_new[:, 0],
+                                 pos, layer)
     out = paged_attend(params, cfg, q, new_cache, page_rows, pos[:, None],
-                       window=window, active=active)
+                       window=window, active=active, layer=layer)
     return out, new_cache
 
 
@@ -545,7 +563,8 @@ def paged_multitok_attention(params, cfg: ModelConfig, x: jax.Array,
                              cache: PagedKVCache, page_rows: jax.Array,
                              position: jax.Array, *,
                              window: Optional[int] = None,
-                             active: Optional[jax.Array] = None):
+                             active: Optional[jax.Array] = None,
+                             layer=None):
     """Multi-token paged attention for ALL slots at once: x (B,T,d) holds T
     consecutive tokens per slot, row b starting at absolute ``position[b]``.
     Every token's K/V is scattered into its slot's pages (inactive rows'
@@ -557,7 +576,9 @@ def paged_multitok_attention(params, cfg: ModelConfig, x: jax.Array,
     draft-verification path (one query per proposed token): a query at
     position p never sees entries with pos > p, so cache entries written
     by later-rejected draft tokens are invisible to every surviving query
-    and are overwritten before the real sequence reaches them.
+    and are overwritten before the real sequence reaches them.  ``layer``
+    selects one layer of stacked pools, as in
+    :func:`paged_decode_attention`.
 
     Returns (out (B,T,d), new_cache)."""
     B, T, _ = x.shape
@@ -565,27 +586,22 @@ def paged_multitok_attention(params, cfg: ModelConfig, x: jax.Array,
         jnp.atleast_1d(jnp.asarray(position, jnp.int32)), (B,))
     qpos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B,T)
     q, k_new, v_new = qkv_project(params, cfg, x, qpos)
-    P, ps = cache.k.shape[0], cache.k.shape[1]
+    P, ps = cache.pos.shape[-2:]
     rows = jnp.broadcast_to(page_rows[:, None, :],
                             (B, T, page_rows.shape[-1]))
     extra = None if active is None else active[:, None]
     with jax.named_scope("kv_write"):
         phys, off, ok = _page_coords(rows, qpos, ps, P, extra_ok=extra)
-        new_k = cache.k.at[phys, off].set(k_new.astype(cache.k.dtype),
-                                          mode="drop")
-        new_v = cache.v.at[phys, off].set(v_new.astype(cache.v.dtype),
-                                          mode="drop")
-        new_pos = cache.pos.at[phys, off].set(qpos, mode="drop")
-    new_cache = PagedKVCache(new_k, new_v, new_pos)
+        new_cache = _write_pages(cache, phys, off, k_new, v_new, qpos, layer)
     out = paged_attend(params, cfg, q, new_cache, page_rows, qpos,
-                       window=window, active=active)
+                       window=window, active=active, layer=layer)
     return out, new_cache
 
 
 def paged_prefill_attention(params, cfg: ModelConfig, x: jax.Array,
                             cache: PagedKVCache, page_row: jax.Array,
                             pos_start: jax.Array, *,
-                            window: Optional[int] = None):
+                            window: Optional[int] = None, layer=None):
     """Chunked-prefill attention for ONE request slot.  x (1,C,d) is one
     prompt chunk starting at absolute position ``pos_start``; a batch-1
     view of :func:`paged_multitok_attention`.
@@ -593,7 +609,8 @@ def paged_prefill_attention(params, cfg: ModelConfig, x: jax.Array,
     Returns (out (1,C,d), new_cache)."""
     return paged_multitok_attention(
         params, cfg, x, cache, page_row[None],
-        jnp.reshape(jnp.asarray(pos_start, jnp.int32), (1,)), window=window)
+        jnp.reshape(jnp.asarray(pos_start, jnp.int32), (1,)), window=window,
+        layer=layer)
 
 
 # ---------------------------------------------------------------------------

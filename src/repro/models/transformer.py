@@ -497,7 +497,15 @@ def _decode_walk(params, cfg: ModelConfig, x: jax.Array, caches, layer_fn):
     x) -> (x, new_entry, counts)`` supplies the per-layer step (contiguous
     decode, paged decode, or paged chunk prefill).  Returns (x, caches,
     counts): the expert layers' per-expert pair counts (expert layers, E)
-    int32 in layer order, None for a model without experts."""
+    int32 in layer order, None for a model without experts.
+
+    The scanned blocks' page pools (``PagedKVCache`` entries) ride the
+    scan's carry as whole stacks, and ``layer_fn(..., x, layer)`` writes
+    and reads layer ``layer`` of them in place: taken as ``xs`` and given
+    back as ``ys``, each step would copy every layer's pool out of the
+    stack and back, though it writes a few rows.  Every other entry
+    (recurrent state, verify's per-step snapshots, contiguous rings) is
+    sliced per layer as before."""
     prefix, pat, n_rep, suffix = stack_plan(cfg)
     new_caches: Dict[str, Any] = {}
     counts = []
@@ -513,22 +521,30 @@ def _decode_walk(params, cfg: ModelConfig, x: jax.Array, caches, layer_fn):
 
     if n_rep:
         sliced, held = _hold_experts(params["blocks"])
+        pools = {k: e for k, e in caches["blocks"].items()
+                 if isinstance(e, attn_mod.PagedKVCache)}
+        rest = {k: e for k, e in caches["blocks"].items() if k not in pools}
 
-        def body(xx, inp):
+        def body(carry, inp):
+            xx, pools = carry
             block_p, block_c, rep = inp
-            entries, cs = [], []
+            pools, entries, cs = dict(pools), {}, []
             for i, kind in enumerate(pat):
-                xx, ne, c = layer_fn(_with_held(block_p, held, i, rep), kind,
-                                     _ffn_kind(cfg, kind, in_prefix=False),
-                                     block_c[str(i)], xx)
-                entries.append(ne)
+                k = str(i)
+                lp = _with_held(block_p, held, i, rep)
+                ffn = _ffn_kind(cfg, kind, in_prefix=False)
+                if k in pools:
+                    xx, pools[k], c = layer_fn(lp, kind, ffn, pools[k], xx,
+                                               rep)
+                else:
+                    xx, entries[k], c = layer_fn(lp, kind, ffn, block_c[k],
+                                                 xx)
                 cs += [c] if c is not None else []
-            return xx, ({str(i): e for i, e in enumerate(entries)},
-                        jnp.stack(cs) if cs else None)
-        x, (block_caches, block_counts) = jax.lax.scan(
-            body, x, (sliced, caches["blocks"], jnp.arange(n_rep)),
+            return (xx, pools), (entries, jnp.stack(cs) if cs else None)
+        (x, pools), (entries, block_counts) = jax.lax.scan(
+            body, (x, pools), (sliced, rest, jnp.arange(n_rep)),
             unroll=_scan_unroll())
-        new_caches["blocks"] = block_caches
+        new_caches["blocks"] = {**entries, **pools}
         if block_counts is not None:        # (n_rep, per pattern, E)
             counts.append(block_counts.reshape(-1, block_counts.shape[-1]))
 
@@ -601,13 +617,13 @@ def decode_step_paged(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     if cfg.contribution_gate:
         x = contribution_gate(params["gate"], x)
 
-    def layer_fn(lp, kind, ffn, ce, xx):
+    def layer_fn(lp, kind, ffn, ce, xx, layer=None):
         def mixer(lp_, kind_, h):
             if kind_ in (ATTN_GLOBAL, ATTN_LOCAL):
                 window = cfg.window if kind_ == ATTN_LOCAL else None
                 return attn_mod.paged_decode_attention(
                     lp_["attn"], cfg, h, ce, page_table, position,
-                    window=window, active=active)
+                    window=window, active=active, layer=layer)
             if kind_ == RECURRENT:
                 out, ne = rglru_mod.rglru_decode_step(lp_["rec"], cfg, h, ce)
             elif kind_ == SSM:
@@ -665,13 +681,13 @@ def verify_step_paged(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     if cfg.contribution_gate:
         x = contribution_gate(params["gate"], x)
 
-    def layer_fn(lp, kind, ffn, ce, xx):
+    def layer_fn(lp, kind, ffn, ce, xx, layer=None):
         def mixer(lp_, kind_, h):
             if kind_ in (ATTN_GLOBAL, ATTN_LOCAL):
                 window = cfg.window if kind_ == ATTN_LOCAL else None
                 return attn_mod.paged_multitok_attention(
                     lp_["attn"], cfg, h, ce, page_table, position,
-                    window=window, active=active)
+                    window=window, active=active, layer=layer)
             if kind_ == RECURRENT:
                 return _verify_recurrent(rglru_mod.rglru_decode_step,
                                          lp_["rec"], cfg, h, ce)
@@ -724,13 +740,13 @@ def prefill_chunk(params, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     if cfg.contribution_gate:
         x = contribution_gate(params["gate"], x)
 
-    def layer_fn(lp, kind, ffn, ce, xx):
+    def layer_fn(lp, kind, ffn, ce, xx, layer=None):
         def mixer(lp_, kind_, h):
             if kind_ in (ATTN_GLOBAL, ATTN_LOCAL):
                 window = cfg.window if kind_ == ATTN_LOCAL else None
                 return attn_mod.paged_prefill_attention(
                     lp_["attn"], cfg, h, ce, page_row, pos_start,
-                    window=window)
+                    window=window, layer=layer)
             if kind_ == RECURRENT:
                 return _chunk_recurrent(rglru_mod.rglru_decode_step,
                                         lp_["rec"], cfg, h, ce, slot,

@@ -38,8 +38,10 @@ def pytest_configure(config):
         '-m "not slow"')
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture(scope="module")
 def rng():
+    # one stream a test module: a module draws the same data whichever
+    # modules a test worker ran before it
     return np.random.default_rng(0)
 
 

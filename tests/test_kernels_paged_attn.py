@@ -80,11 +80,26 @@ LAYOUTS = {
 }
 
 
+def _stacked(rng, cache, layer, n=3):
+    """``cache`` as layer ``layer`` of an ``n``-layer stack whose other
+    layers hold other keys and values and no positions: a read of the
+    wrong layer sees other keys, or none."""
+    def other(a):
+        return jnp.asarray(rng.normal(0, 1, a.shape), a.dtype)
+    k = [cache.k if j == layer else other(cache.k) for j in range(n)]
+    v = [cache.v if j == layer else other(cache.v) for j in range(n)]
+    pos = [cache.pos if j == layer else jnp.full_like(cache.pos, -1)
+           for j in range(n)]
+    return PagedKVCache(jnp.stack(k), jnp.stack(v), jnp.stack(pos))
+
+
 def _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap, *, D=16,
-                 poison=False):
+                 poison=False, layer=None):
     """Kernel vs oracle on ``LAYOUTS[layout]``: (got, want, active).
     ``poison`` fills every pool page outside the rows' live extents with
-    NaN before the kernel runs; the oracle sees the clean pool."""
+    NaN before the kernel runs; the oracle sees the clean pool.  With
+    ``layer`` the kernel reads the pool as that layer of a stack of
+    three (``_stacked``), the oracle the layer alone."""
     L = LAYOUTS[layout]
     cache, rows = _pool_and_slots(rng, L["lens"], Hkv=Hkv, D=D, n=L["n"],
                                   ps=L["ps"])
@@ -105,7 +120,9 @@ def _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap, *, D=16,
         assert (~live).any()
         k = jnp.where(jnp.asarray(live)[:, None, None, None], k, jnp.nan)
         v = jnp.where(jnp.asarray(live)[:, None, None, None], v, jnp.nan)
-    got = paged_attention_fused(q, k, v, cache.pos, rows, qpos, active,
+    pools = (k, v, cache.pos) if layer is None \
+        else _stacked(rng, PagedKVCache(k, v, cache.pos), layer)
+    got = paged_attention_fused(q, *pools, rows, qpos, active, layer,
                                 window=window, softcap=softcap)
     want = paged_attention_ref(
         q.reshape(B, Tq, Hkv, G, D), cache.k, cache.v, cache.pos, rows,
@@ -115,25 +132,41 @@ def _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap, *, D=16,
     return np.asarray(got), np.asarray(want)
 
 
-_CASES = [pytest.param(Hq, Hkv, Tq, w, c, "ragged",
+_CASES = [pytest.param(Hq, Hkv, Tq, w, c, "ragged", None,
                        id=f"{w}-{c}-{Tq}-{Hq}-{Hkv}")
           for Hq, Hkv in [(4, 2), (2, 2), (4, 1)] for Tq in [1, 4, 7]
           for w, c in [(0, 0.0), (6, 0.0), (0, 30.0), (6, 30.0)]] + [
-    pytest.param(4, 2, 1, 0, 0.0, "blocks", id="blocks-decode"),
-    pytest.param(4, 2, 7, 40, 0.0, "blocks", id="blocks-chunk-window"),
-    pytest.param(4, 1, 4, 40, 30.0, "blocks", id="blocks-verify-window-cap"),
-    pytest.param(2, 2, 1, 0, 30.0, "blocks", id="blocks-decode-cap"),
-    pytest.param(4, 2, 4, 40, 0.0, "odd-pages", id="odd-pages-window"),
-    pytest.param(4, 2, 4, 200, 30.0, "wide-pages", id="wide-pages-window"),
+    pytest.param(4, 2, 1, 0, 0.0, "blocks", None, id="blocks-decode"),
+    pytest.param(4, 2, 7, 40, 0.0, "blocks", None, id="blocks-chunk-window"),
+    pytest.param(4, 1, 4, 40, 30.0, "blocks", None,
+                 id="blocks-verify-window-cap"),
+    pytest.param(2, 2, 1, 0, 30.0, "blocks", None, id="blocks-decode-cap"),
+    pytest.param(4, 2, 4, 40, 0.0, "odd-pages", None, id="odd-pages-window"),
+    pytest.param(4, 2, 4, 200, 30.0, "wide-pages", None,
+                 id="wide-pages-window"),
+] + [
+    # layer ``i`` of a stacked pool, read in place: each layer, decode and
+    # chunk shapes, and a page size whose positions are padded
+    pytest.param(4, 2, Tq, w, 0.0, layout, i, id=f"stacked-{name}-layer{i}")
+    for Tq, w, layout, name in [(1, 0, "blocks", "decode"),
+                                (7, 40, "blocks", "chunk-window"),
+                                (4, 40, "odd-pages", "odd-pages")]
+    for i in range(3)
 ]
 
 
-@pytest.mark.parametrize("Hq,Hkv,Tq,window,softcap,layout", _CASES)
-def test_fused_matches_ref(rng, Hq, Hkv, Tq, window, softcap, layout):
+@pytest.mark.parametrize("Hq,Hkv,Tq,window,softcap,layout,layer", _CASES)
+def test_fused_matches_ref(rng, Hq, Hkv, Tq, window, softcap, layout,
+                           layer):
     """Decode (Tq=1), verify (Tq=k+1) and prefill-chunk (Tq=chunk) shapes
     vs the oracle, over ragged slot lengths and shuffled page tables, in
-    one-block rows and in rows the kernel walks block by block."""
-    got, want = _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap)
+    one-block rows and in rows the kernel walks block by block; and on
+    one layer of a stacked pool, against the oracle on that layer
+    alone."""
+    if layer is not None:       # own draws: the shared stream stays as it was
+        rng = np.random.default_rng(layer)
+    got, want = _blocks_case(rng, layout, Hq, Hkv, Tq, window, softcap,
+                             layer=layer)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
@@ -277,19 +310,30 @@ def _attn_setup(rng, key, arch="qwen2-1.5b", **over):
     return cfg, params, cache, rows
 
 
-@pytest.mark.parametrize("arch,over,active", [
-    pytest.param("qwen2-1.5b", {}, None, id="qwen2-1.5b-over0"),
+@pytest.mark.parametrize("arch,over,active,layer", [
+    pytest.param("qwen2-1.5b", {}, None, None, id="qwen2-1.5b-over0"),
     # windowed + softcapped GQA
-    pytest.param("gemma2-2b", {"window": 6}, None, id="gemma2-2b-over1"),
+    pytest.param("gemma2-2b", {"window": 6}, None, None,
+                 id="gemma2-2b-over1"),
     # row 1 inactive: the kernel walks none of its pages
-    pytest.param("qwen2-1.5b", {}, [True, False], id="qwen2-1.5b-active"),
+    pytest.param("qwen2-1.5b", {}, [True, False], None,
+                 id="qwen2-1.5b-active"),
+    # the middle layer of a stack of three, written and read in place
+    pytest.param("qwen2-1.5b", {}, [True, False], 1,
+                 id="qwen2-1.5b-stacked"),
+    pytest.param("gemma2-2b", {"window": 6}, None, 2,
+                 id="gemma2-2b-stacked"),
 ])
 def test_dispatch_parity_multitok_and_decode(rng, key, monkeypatch,
-                                             arch, over, active):
+                                             arch, over, active, layer):
     """paged_multitok_attention (the verify/prefill path) and
     paged_decode_attention produce allclose outputs on active rows and
-    IDENTICAL caches under REPRO_PAGED_ATTN=1 vs =0."""
+    IDENTICAL caches under REPRO_PAGED_ATTN=1 vs =0, on a pool and on
+    one layer of a stacked pool."""
     cfg, params, cache, rows = _attn_setup(rng, key, arch, **over)
+    if layer is not None:       # own draws: the shared stream stays as it was
+        rng = np.random.default_rng(layer)
+        cache = _stacked(rng, cache, layer)
     B, Tq = rows.shape[0], 3
     window = cfg.window if arch == "gemma2-2b" else None
     mask = None if active is None else jnp.asarray(active)
@@ -300,9 +344,11 @@ def test_dispatch_parity_multitok_and_decode(rng, key, monkeypatch,
     for flag in ("0", "1"):
         monkeypatch.setenv("REPRO_PAGED_ATTN", flag)
         o_m, c = paged_multitok_attention(params, cfg, x, cache, rows, pos0,
-                                          window=window, active=mask)
+                                          window=window, active=mask,
+                                          layer=layer)
         o_d, c = paged_decode_attention(params, cfg, xd, c, rows, pos0 + Tq,
-                                        window=window, active=mask)
+                                        window=window, active=mask,
+                                        layer=layer)
         outs[flag] = (o_m, o_d)
         caches[flag] = c
     # post-projection outputs accumulate O(d_model) reassociation noise;
@@ -314,6 +360,50 @@ def test_dispatch_parity_multitok_and_decode(rng, key, monkeypatch,
                                    atol=5e-4)
     for a, b in zip(caches["0"], caches["1"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("layer", range(3))
+@pytest.mark.parametrize("T", [1, 3], ids=["decode", "chunk"])
+def test_stacked_step_writes_only_its_rows(key, layer, T):
+    """A step on layer ``layer`` of a three-layer stack writes each active
+    row's new K/V/pos entries at (layer, page, offset) and nothing else:
+    row 1's writes past its first page fall on an unassigned (-1) page
+    and row 2 is inactive, so theirs are dropped (out of bounds on the
+    page axis, never the next layer's first page).  Every other layer,
+    and every other entry of the written layer, stays bit-identical."""
+    from repro.distributed.sharding import ParamFactory
+    from repro.models.attention import attn_params
+    rng = np.random.default_rng(layer)
+    cfg = smoke_variant(get_config("qwen2-1.5b"))
+    params = attn_params(ParamFactory(key), cfg)
+    Hkv, D, P = cfg.num_kv_heads, cfg.resolved_head_dim(), 8
+    shape = (3, P, PS, Hkv, D)
+    old = PagedKVCache(
+        jnp.asarray(rng.normal(0, 1, shape), jnp.float32),
+        jnp.asarray(rng.normal(0, 1, shape), jnp.float32),
+        jnp.asarray(rng.integers(0, 99, shape[:3]), jnp.int32))
+    rows = np.asarray([[0, 1, 2], [3, -1, -1], [4, 5, -1]], np.int32)
+    pos0 = np.asarray([3, 5 - T, 2], np.int32)  # row 1 ends at position 4
+    active = np.asarray([True, True, False])
+    x = jnp.asarray(rng.normal(0, 1, (3, T, cfg.d_model)), jnp.float32)
+    step = paged_decode_attention if T == 1 else paged_multitok_attention
+    _, new = step(params, cfg, x, old, jnp.asarray(rows),
+                  jnp.asarray(pos0), active=jnp.asarray(active),
+                  layer=layer)
+    want_pos = np.array(old.pos)
+    written = np.zeros(shape[:3], bool)
+    for b in np.flatnonzero(active):
+        for p in range(pos0[b], pos0[b] + T):
+            page = rows[b, p // PS]
+            if page >= 0:
+                want_pos[layer, page, p % PS] = p
+                written[layer, page, p % PS] = True
+    assert written.sum() == 2 * T - 1           # row 1's last is dropped
+    np.testing.assert_array_equal(np.asarray(new.pos), want_pos)
+    for a, b in [(new.k, old.k), (new.v, old.v)]:
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a[~written], b[~written])
+        assert (a[written] != b[written]).all()
 
 
 # ---------------------------------------------------------------------------
@@ -333,12 +423,24 @@ def _serve(cfg, lens, *, spec_k=0, drafter=None, prefill_chunk=0,
     return sched.run(reqs)
 
 
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "gemma2-2b"])
-def test_e2e_greedy_parity_kernel_vs_lax(monkeypatch, arch):
+@pytest.mark.parametrize("arch,layers", [
+    pytest.param("qwen2-1.5b", 0, id="qwen2-1.5b"),
+    pytest.param("gemma2-2b", 0, id="gemma2-2b"),
+    # three and two scan repetitions: layers past the first read and
+    # write their own layer of the carried pools (recurrentgemma's
+    # recurrent state stays sliced per layer beside them)
+    pytest.param("qwen2-1.5b", 6, id="qwen2-1.5b-6layers"),
+    pytest.param("qwen3-moe-30b-a3b", 6, id="qwen3-moe-30b-a3b-6layers"),
+    pytest.param("recurrentgemma-2b", 4, id="recurrentgemma-2b-4layers"),
+])
+def test_e2e_greedy_parity_kernel_vs_lax(monkeypatch, arch, layers):
     """The acceptance bar: decode + chunked prefill + speculative verify
     served entirely through the fused kernel emit streams bit-identical
-    to the lax fallback, on a plain-GQA and a windowed+softcapped arch."""
+    to the lax fallback, on a plain-GQA and a windowed+softcapped arch,
+    and on stacks of more than one scan repetition."""
     cfg = smoke_variant(get_config(arch))
+    if layers:
+        cfg = cfg.replace(num_layers=layers)
     lens = [8, 5, 7, 6]
     runs = {}
     for flag in ("0", "1"):

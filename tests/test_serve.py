@@ -5,6 +5,7 @@ ragged prompts, arrival-order determinism), and the train -> ckpt -> serve
 hand-off."""
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -72,11 +73,22 @@ def _serve(cfg, reqs, *, slots, eos=None, mesh=None, max_len=None,
 # Prefill + decode parity: greedy tokens off the incremental cache path must
 # bit-match a teacher-forced full-sequence forward argmax, for every arch
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("arch", DECODE_ARCHS)
-def test_prefill_decode_parity(arch):
+@pytest.mark.parametrize("arch,layers", [
+    pytest.param(a, 0, id=a) for a in DECODE_ARCHS] + [
+    # paged serving (chunks and decode) over three and two scan
+    # repetitions: each layer past the first reads and writes its own
+    # layer of the carried pools
+    pytest.param(a, n, id=f"{a}-{n}layers-paged")
+    for a, n in [("qwen2-1.5b", 6), ("qwen3-moe-30b-a3b", 6),
+                 ("recurrentgemma-2b", 4)]])
+def test_prefill_decode_parity(arch, layers):
     cfg = _ample_moe(smoke_variant(get_config(arch)))
+    kw = {}
+    if layers:
+        cfg = cfg.replace(num_layers=layers)
+        kw = dict(paged=True, page_size=4, prefill_chunk=3)
     reqs = _requests(cfg, [PROMPT, PROMPT])
-    out, _ = _serve(cfg, reqs, slots=2)
+    out, _ = _serve(cfg, reqs, slots=2, **kw)
     # reference: full-sequence forward over prompt + generated (the params
     # in the engine state were donated — re-init the identical tree)
     params = T.init(cfg, jax.random.key(0))
@@ -766,6 +778,63 @@ def test_paged_decode_names_each_layer_part(op_name_scopes):
     state = eng.init_state(T.init(cfg, jax.random.key(0)))
     text = eng.lower_decode(state).as_text(debug_info=True)
     assert {"attn", "mlp", "kv_write", "logits"} <= op_name_scopes(text)
+
+
+def _pool_copies(hlo: str, shapes) -> list:
+    """Instructions of compiled HLO text that slice, update or copy an
+    array whose type is in ``shapes`` (as "f32[3,32,4,1,64]"), found by
+    opcode or by the name of the fusion that holds the op."""
+    out = []
+    for m in re.finditer(r"%([\w.-]+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(",
+                         hlo):
+        name, typ, op = m.groups()
+        what = f"{op} {name}".replace("_", "-")
+        if typ in shapes and re.search(
+                r"dynamic-slice|dynamic-update-slice|\bcopy", what):
+            out.append(m.group(0))
+    return out
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk", "verify"])
+def test_paged_steps_update_the_stacked_pool_in_place(monkeypatch, step):
+    """The layer scan carries the stacked page pools and writes each
+    step's rows into them in place: the compiled decode, chunk and
+    verify programs of a 6-layer qwen2 (3 scan repetitions) hold no
+    slice of one layer's pool out of the stack, and no update or copy
+    of a whole stack (K, V or positions)."""
+    # the served programs scan their layers; importing the dry run (as
+    # another test module does) sets its unrolled accounting mode
+    monkeypatch.setenv("REPRO_SCAN_UNROLL", "0")
+    cfg = smoke_variant(get_config("qwen2-1.5b")).replace(num_layers=6)
+    assert T.stack_plan(cfg)[2] == 3
+    eng = InferenceEngine(cfg, slots=4, dtype=jnp.float32, max_len=32,
+                          paged=True, page_size=4, prefill_chunk=4)
+    state = eng.init_state(T.init(cfg, jax.random.key(0)))
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    if step == "decode":
+        lowered = eng.lower_decode(state)
+    elif step == "chunk":
+        inputs = {"tokens": jnp.zeros((1, 4), jnp.int32)}
+        jfn = eng._get_jit("chunk", state, inputs)
+        lowered = eng._run(jfn.lower, state, inputs, i32(0), i32(0))
+    else:
+        drafts = jnp.zeros((4, 3), jnp.int32)
+        jfn = eng._get_jit("verify", state, {"drafts": drafts})
+        lowered = eng._run(jfn.lower, state, drafts, i32([3] * 4),
+                           jnp.ones((4,), bool))
+    hlo = lowered.compile().as_text()
+    stacks = jax.tree.leaves(state.cache["blocks"])
+    assert {a.shape[0] for a in stacks} == {3}
+
+    def typ(a, shape):
+        dt = {"float32": "f32", "int32": "s32"}[str(a.dtype)]
+        return f"{dt}[{','.join(map(str, shape))}]"
+    shapes = {typ(a, sh) for a in stacks
+              for sh in (a.shape, a.shape[1:], (1,) + a.shape[1:])}
+    assert _pool_copies(hlo, shapes) == []
+    # the step still writes the pools: by scatters into the stacks
+    assert any(typ(a, a.shape) in line for a in stacks
+               for line in hlo.splitlines() if " scatter(" in line)
 
 
 def test_paged_spans_count_live_and_walked_pages():

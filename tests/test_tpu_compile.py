@@ -93,6 +93,44 @@ def test_paged_attention_compiles_natively(one_chip, arch, shape):
                      text)
 
 
+# the served cells' stacks: (layers, pages, slots, pages a slot)
+STACKS = {"qwen2-1.5b": (28, 8448, 16, 528),
+          "qwen3-moe-30b-a3b": (6, 5120, 64, 80)}
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk"])
+@pytest.mark.parametrize("arch", list(STACKS))
+def test_stacked_paged_attention_compiles_natively(one_chip, arch, shape):
+    """Inside the layer scan the kernel reads one layer of the served
+    cells' whole K/V stacks in place, by a layer index in scalar-prefetch
+    memory: the compiled program holds the kernel and no copy of a
+    layer's pool."""
+    Hkv, G, D, window, softcap = WIDTHS[arch]
+    L, P, slots, n = STACKS[arch]
+    B, T = (slots, 1) if shape == "decode" else (1, CHUNK)
+
+    def spec(shp, dtype):
+        return jax.ShapeDtypeStruct(shp, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda q, k, v, pos, rows, qpos, active, layer: (
+        paged_attention_pallas(q, k, v, pos, rows, qpos, active, layer,
+                               window=window, softcap=softcap,
+                               interpret=False)))
+    text = fn.lower(
+        spec((B, T, Hkv, G, D), jnp.bfloat16),
+        spec((L, P, PS, Hkv, D), jnp.bfloat16),
+        spec((L, P, PS, Hkv, D), jnp.bfloat16),
+        spec((L, P, PS), jnp.int32), spec((B, n), jnp.int32),
+        spec((B, T), jnp.int32), spec((B,), jnp.bool_),
+        spec((), jnp.int32)).compile().as_text()
+    assert re.search(r"%paged_attention(\.\d+)? = [^\n]*tpu_custom_call",
+                     text)
+    layer_pool = f"bf16[{P},{PS},{Hkv},{D}]"
+    assert not [line for line in text.splitlines()
+                if f"= {layer_pool}" in line or
+                f"= bf16[1,{P},{PS},{Hkv},{D}]" in line]
+
+
 # the served qwen3-moe-30b-a3b cell: hidden 2,048, expert hidden 768, 128
 # experts, top-8; (token, expert) pairs of a 64-slot decode step and of a
 # 256-token prefill chunk; gate/up (d -> f) and down (f -> d)
