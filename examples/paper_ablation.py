@@ -7,6 +7,8 @@ Isolates each Dom-ST ingredient on a fixed watershed set:
   D. - normalization in Pix-Con
   E. contribution gate on an LM arch (the generalized Pix-Con; DESIGN.md §5):
      train qwen2-smoke with/without cfg.contribution_gate on Zipf tokens.
+
+    PYTHONPATH=src python examples/paper_ablation.py
 """
 from __future__ import annotations
 

@@ -5,6 +5,8 @@ Reproduces the paper's claims on the synthetic 23-watershed dataset:
   * (+P) improves most watersheds (~91% in the paper),
   * Dom-ST beats both baselines on most watersheds,
   * highest individual NSE increase (paper: up to 93%).
+
+    PYTHONPATH=src python examples/paper_fig3_nse.py [--full]
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@
 Every architecture (the paper's Dom-ST plus the 10 assigned public
 architectures) is described by a frozen dataclass tree.  Configs are pure
 data: they never touch jax device state, so importing a config is always
-safe inside tests / the dry-run launcher.
+safe inside tests and before a launcher sets its device flags.
 
 Layer heterogeneity (gemma2's local/global alternation, recurrentgemma's
 rec/rec/attn pattern) is expressed with ``layer_pattern``: a tuple of layer

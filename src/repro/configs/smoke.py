@@ -1,8 +1,8 @@
 """Reduced smoke-test variants: 2 layers, d_model<=512, <=4 experts.
 
-The FULL configs are exercised only via the dry-run (ShapeDtypeStruct, no
-allocation); CPU smoke tests instantiate these reduced variants of the same
-family and run one forward/train step.
+The FULL configs are exercised only through ShapeDtypeStruct input specs
+(``launch/steps.py``, no allocation); CPU smoke tests instantiate these
+reduced variants of the same family and run one forward/train step.
 """
 from __future__ import annotations
 

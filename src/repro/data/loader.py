@@ -28,8 +28,7 @@ This module closes that gap with two pieces:
     ``for batch in loader: state, m = engine.step(state, batch)``.
 
 ``prefetch=0`` degrades to the synchronous path (same batches, same
-placement, no thread) — the parity baseline for tests and for
-``benchmarks/loader_bench.py``.
+placement, no thread) — the parity baseline for tests.
 """
 from __future__ import annotations
 

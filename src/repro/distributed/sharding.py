@@ -45,14 +45,11 @@ def is_axes(x: Any) -> bool:
 # ---------------------------------------------------------------------------
 def make_rules(cfg=None, *, mesh: Optional[Mesh] = None,
                tp_mode: Optional[str] = None,
-               sequence_parallel: bool = False,
                fsdp: bool = False) -> dict[str, MeshAxis]:
     """Logical axis -> mesh axis assignment.
 
     ``cfg`` (a ModelConfig) supplies ``tp_mode``; ``mesh`` determines whether
-    a "pod" axis exists.  ``sequence_parallel`` additionally shards the
-    ``seq`` activation axis over "model" (a beyond-paper optimization used
-    in the §Perf hillclimbs).  ``fsdp=True`` is the PARAMETER rule variant:
+    a "pod" axis exists.  ``fsdp=True`` is the PARAMETER rule variant:
     the ``embed`` dim of params/optimizer state shards over the data axes
     (ZeRO-3-style; GSPMD inserts the weight all-gathers) — use it for the
     param/opt trees only, never for activation constraints.
@@ -66,7 +63,7 @@ def make_rules(cfg=None, *, mesh: Optional[Mesh] = None,
 
     rules: dict[str, MeshAxis] = {
         "batch": batch,
-        "seq": model if sequence_parallel else None,
+        "seq": None,
         "embed": batch if fsdp else None,
         "heads": model if tp == "heads" else None,
         "kv_heads": model if tp == "heads" else None,

@@ -1,8 +1,8 @@
 """Mesh construction — the one place a ``jax.sharding.Mesh`` is built.
 
 FUNCTIONS, not module-level constants — importing this module never
-touches jax device state (required so smoke tests see 1 CPU device while
-the dry-run sees 512 placeholder devices).
+touches jax device state, so a process can still set its own
+``XLA_FLAGS`` host-device count after importing it.
 
 Every axis is ``AxisType.Auto``: the sharding rules in
 ``distributed/sharding.py`` place arrays with ``NamedSharding`` and let
@@ -17,11 +17,6 @@ import math
 
 import jax
 from jax.sharding import AxisType
-
-# TPU v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
 
 
 def make_mesh(shape, *, devices=None):

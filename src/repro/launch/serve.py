@@ -29,7 +29,7 @@ both running through ``repro/serve/``:
     (params + cache pool + page tables + slot position counters) is
     sharded from the ``distributed/sharding.py`` rule tables, so the same
     script drives the production mesh (decode_32k / long_500k shapes)
-    that the dry-run lowers.  ``--temperature/--top-k/--top-p/
+    that ``launch/steps.py`` describes.  ``--temperature/--top-k/--top-p/
     --rep-penalty/--sample-seed`` switch requests from greedy argmax to
     per-request seeded sampling (heterogeneous configs per request via
     ``--queue file.json``); sampled streams stay deterministic — and

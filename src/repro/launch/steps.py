@@ -1,4 +1,4 @@
-"""Step functions + input specs + sharding trees for launch/dryrun/train.
+"""Step functions + input specs + sharding trees for lowering and training.
 
 Everything here is mesh-agnostic until ``build_sharded_step`` binds a mesh
 and rule table.  ``input_specs`` returns ShapeDtypeStruct stand-ins (weak-
@@ -157,10 +157,7 @@ def cache_pspecs(cfg: ModelConfig, cache_struct, mesh, rules):
 
 
 def lower_step(cfg: ModelConfig, shape_name: str, mesh, *,
-               tc: Optional[TrainConfig] = None,
-               sequence_parallel: bool = False,
-               serve_bf16: bool = False,
-               extra_rules: Optional[dict] = None):
+               tc: Optional[TrainConfig] = None):
     """Build + lower the right step for (cfg, shape) on ``mesh``.
 
     Returns (lowered, kind).  ``.compile()`` on the result proves the
@@ -168,21 +165,11 @@ def lower_step(cfg: ModelConfig, shape_name: str, mesh, *,
     """
     shape = INPUT_SHAPES[shape_name]
     tc = tc or TrainConfig(remat="block")
-    rules = make_rules(cfg, mesh=mesh, sequence_parallel=sequence_parallel)
-    if extra_rules:
-        rules.update(extra_rules)
+    rules = make_rules(cfg, mesh=mesh)
     # params/optimizer may use the FSDP rule variant (embed dim over data);
     # activation constraints always use the plain rules
     prules = make_rules(cfg, mesh=mesh, fsdp=True) if tc.fsdp else rules
     pspecs, param_shapes = param_pspecs(cfg, mesh, prules)
-    if serve_bf16 and shape.kind in ("prefill", "decode"):
-        # serving checkpoints are bf16 (halves weight-resident HBM; the
-        # model casts at use sites anyway)
-        param_shapes = jax.tree.map(
-            lambda st: jax.ShapeDtypeStruct(
-                st.shape, jnp.bfloat16
-                if jnp.issubdtype(st.dtype, jnp.floating) else st.dtype),
-            param_shapes)
     specs = input_specs(cfg, shape_name)
     b_pspecs = batch_pspecs(cfg, shape, mesh, rules, specs["batch"])
 

@@ -15,7 +15,6 @@ Public API:
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -38,16 +37,6 @@ from repro.models.layers import (
 )
 
 LayerKind = str
-
-
-def _scan_unroll() -> bool:
-    """Fully unroll layer/loss scans (dry-run accounting mode).
-
-    XLA's cost_analysis counts a while-loop body ONCE regardless of trip
-    count, so the dry-run sets REPRO_SCAN_UNROLL=1 to unroll the scans and
-    make HLO FLOPs / collective-bytes reflect the whole program.
-    """
-    return os.environ.get("REPRO_SCAN_UNROLL", "0") == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -389,8 +378,7 @@ def run_stack(params, cfg: ModelConfig, x: jax.Array, *,
         if remat:
             body = jax.checkpoint(body)
         (x, aux), block_caches = jax.lax.scan(
-            body, (x, aux), (sliced, jnp.arange(n_rep)),
-            unroll=_scan_unroll())
+            body, (x, aux), (sliced, jnp.arange(n_rep)))
         if collect_cache:
             caches["blocks"] = block_caches
 
@@ -469,7 +457,7 @@ def lm_loss(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
 
     (tot, cnt), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xs, ts, ms), unroll=_scan_unroll())
+        (xs, ts, ms))
     loss = tot / jnp.maximum(cnt, 1.0)
     if cfg.moe is not None:
         loss = loss + cfg.moe.aux_loss_coef * aux
@@ -542,8 +530,7 @@ def _decode_walk(params, cfg: ModelConfig, x: jax.Array, caches, layer_fn):
                 cs += [c] if c is not None else []
             return (xx, pools), (entries, jnp.stack(cs) if cs else None)
         (x, pools), (entries, block_counts) = jax.lax.scan(
-            body, (x, pools), (sliced, rest, jnp.arange(n_rep)),
-            unroll=_scan_unroll())
+            body, (x, pools), (sliced, rest, jnp.arange(n_rep)))
         new_caches["blocks"] = {**entries, **pools}
         if block_counts is not None:        # (n_rep, per pattern, E)
             counts.append(block_counts.reshape(-1, block_counts.shape[-1]))
@@ -803,7 +790,7 @@ def scatter_prefill_paged(cfg: ModelConfig, paged_caches, prefill_caches,
 
 
 # ---------------------------------------------------------------------------
-# Cache construction (and ShapeDtypeStruct specs for the dry-run)
+# Cache construction (and ShapeDtypeStruct specs for lowering)
 # ---------------------------------------------------------------------------
 def _layer_cache(cfg: ModelConfig, kind: LayerKind, batch: int, max_len: int,
                  dtype):

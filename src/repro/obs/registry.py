@@ -143,9 +143,8 @@ def _fmt_q(q: float) -> str:
 
 def percentiles(values, qs: Iterable[float] = (50, 99)) -> Dict[str, float]:
     """``{"p50": ..., "p99": ...}`` over ``values`` — THE percentile
-    helper every consumer shares (``launch/serve.py`` for the JSON
-    summary, ``benchmarks/serve_bench.py`` for its latency rows), so the
-    interpolation rule can never drift between them.  NaNs on empty."""
+    helper (``launch/serve.py`` uses it for the JSON summary), so the
+    interpolation rule lives in one place.  NaNs on empty."""
     vals = np.asarray(list(values), np.float64)
     if vals.size == 0:
         return {f"p{_fmt_q(q)}": float("nan") for q in qs}
@@ -155,9 +154,9 @@ def percentiles(values, qs: Iterable[float] = (50, 99)) -> Dict[str, float]:
 class Series(MutableMapping):
     """Labeled value family (``name{label} -> float``) with a plain dict
     API — e.g. ``serve.ttft_s`` keyed by request id.  The scheduler's
-    legacy ``sched.ttft`` dict is exactly this view, so existing callers
-    (``benchmarks/serve_bench.py``) keep indexing it unchanged while the
-    registry snapshot/dump sees every point."""
+    legacy ``sched.ttft`` dict is exactly this view, so callers
+    (``launch/serve.py``, ``tests/test_obs.py``) index it as a dict while
+    the registry snapshot/dump sees every point."""
 
     def __init__(self, name: str) -> None:
         self.name = name
@@ -307,8 +306,8 @@ class MetricRegistry:
         """One JSON object per line: ``{"name", "type", ...}`` —
         counters/gauges carry ``value``, histograms their summary
         (count/sum/min/max/mean/p50/p90/p99), series one line per label.
-        The schema ``benchmarks/README.md`` documents and the ``obs`` CI
-        smoke validates."""
+        The schema the ``obs`` CI smoke (``scripts/ci_smoke.py obs``)
+        validates."""
         with open(path, "w") as f:
             for name, doc in self._lines():
                 f.write(json.dumps({"name": name, **_finite(doc)}) + "\n")

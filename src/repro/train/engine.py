@@ -103,8 +103,8 @@ class Engine:
         self.donate = donate
         # mesh and rule tables are built LAZILY: with explicit_shardings
         # off they are never consumed, and constructing a mesh here would
-        # touch jax device state before e.g. the dry-run launcher injects
-        # its XLA_FLAGS device count (see launch/mesh.py)
+        # touch jax device state before e.g. a subprocess test sets its
+        # XLA_FLAGS device count (see launch/mesh.py)
         self._mesh = mesh
         self._rules = rules
         self._param_rules = param_rules

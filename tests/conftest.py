@@ -1,5 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests must see the
-real single CPU device (the 512-device override is dryrun.py-only)."""
+real single CPU device (subprocess tests set their own device count)."""
 import os
 import re
 import sys

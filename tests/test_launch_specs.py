@@ -1,11 +1,16 @@
-"""launch/steps input specs + mesh constructor (pure shape logic, 1 device)."""
+"""launch/steps input specs (pure shape logic, 1 device), and the
+import-time contract of the launch, model and serving packages."""
+import os
+import subprocess
+import sys
+
 import jax
-import jax.numpy as jnp
 import pytest
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
 from repro.launch.steps import batch_struct, input_specs
-from repro.launch.dryrun import matrix, parse_collectives
+
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
@@ -38,31 +43,30 @@ def test_decode_specs_have_cache(arch):
     assert total > 0
 
 
-def test_matrix_has_documented_skips():
-    combos = matrix()
-    assert len(combos) == 32
-    archs = {a for a, _ in combos}
-    assert "gemma2-2b-localonly" in archs          # long-context variant
-    assert ("hubert-xlarge", "decode_32k") not in combos
-    assert ("olmo-1b", "long_500k") not in combos
-    assert ("mamba2-130m", "long_500k") in combos
-    assert ("recurrentgemma-2b", "long_500k") in combos
-
-
-def test_parse_collectives():
-    hlo = """
-  %ar = f32[2,4] all-reduce(%x), replica_groups={}
-  %ag.1 = bf16[8,16] all-gather(%y), dims={0}
-  %a2a = (f32[4], f32[4]) all-to-all(%p, %q)
-  %cp-start = f32[2] collective-permute-start(%z)
+@pytest.mark.parametrize("package", ["repro.launch", "repro.models",
+                                     "repro.serve"])
+def test_importing_a_package_leaves_the_environment_alone(package):
+    """Importing any module of ``package`` sets or changes no variable
+    that steers this program or JAX (``REPRO_*``, ``XLA_*``, ``JAX_*``,
+    ``LIBTPU*``): a test worker that collects a module runs every later
+    test under the environment it started with.  The snapshot is taken
+    after ``jax`` and its Pallas TPU module are imported, since those
+    may set variables of their own."""
+    code = f"""
+import importlib, os, pkgutil
+import jax, jax.experimental.pallas.tpu
+before = dict(os.environ)
+pkg = importlib.import_module({package!r})
+for m in pkgutil.walk_packages(pkg.__path__, pkg.__name__ + "."):
+    importlib.import_module(m.name)
+prefixes = ("REPRO_", "XLA_", "JAX_", "LIBTPU")
+changed = sorted(k for k in set(before) | set(os.environ)
+                 if k.startswith(prefixes)
+                 and before.get(k) != os.environ.get(k))
+print("CHANGED", changed)
 """
-    out = parse_collectives(hlo)
-    assert out["bytes"]["all-reduce"] == 32
-    assert out["bytes"]["all-gather"] == 256
-    assert out["bytes"]["all-to-all"] == 32
-    assert out["counts"]["collective-permute"] == 1
-
-
-def test_mesh_constants():
-    from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
-    assert PEAK_FLOPS_BF16 == 197e12 and HBM_BW == 819e9 and ICI_BW == 50e9
+    env = dict(os.environ, PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "CHANGED []" in out.stdout, out.stdout[-1000:]

@@ -796,15 +796,12 @@ def _pool_copies(hlo: str, shapes) -> list:
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk", "verify"])
-def test_paged_steps_update_the_stacked_pool_in_place(monkeypatch, step):
+def test_paged_steps_update_the_stacked_pool_in_place(step):
     """The layer scan carries the stacked page pools and writes each
     step's rows into them in place: the compiled decode, chunk and
     verify programs of a 6-layer qwen2 (3 scan repetitions) hold no
     slice of one layer's pool out of the stack, and no update or copy
     of a whole stack (K, V or positions)."""
-    # the served programs scan their layers; importing the dry run (as
-    # another test module does) sets its unrolled accounting mode
-    monkeypatch.setenv("REPRO_SCAN_UNROLL", "0")
     cfg = smoke_variant(get_config("qwen2-1.5b")).replace(num_layers=6)
     assert T.stack_plan(cfg)[2] == 3
     eng = InferenceEngine(cfg, slots=4, dtype=jnp.float32, max_len=32,

@@ -15,7 +15,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def test_paper_ablation_direction(key):
     """The paper's Fig-3 ordering on one synthetic watershed:
     Dom-ST (pixcon+multihead+P) >= Singlehead baseline after equal training.
-    (Full 23-watershed comparison lives in benchmarks/fig3_nse.py.)"""
+    (Full 23-watershed comparison lives in examples/paper_fig3_nse.py.)"""
     from repro.configs import TrainConfig, get_config
     from repro.core import domst
     from repro.data import generate_watershed, make_training_windows
@@ -98,9 +98,10 @@ def test_train_cli_domst():
     assert "mean_nse" in out.stdout
 
 
-def test_dryrun_small_mesh():
-    """lower+compile a smoke config on a 2x2 host-device mesh (subprocess
-    so the 4-device XLA flag doesn't leak into this test session)."""
+def test_lower_step_compiles_on_a_small_mesh():
+    """``launch.steps.lower_step`` lowers and compiles a smoke config's
+    train step on a 2x2 host-device mesh (subprocess so the 4-device XLA
+    flag doesn't leak into this test session)."""
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
